@@ -6,7 +6,8 @@
 Prints ``name,us_per_call,derived`` CSV rows (one per measured cell).
 ``--json`` additionally makes the engine benchmark write its machine-
 readable result (default ``BENCH_engine.json``) so CI can diff the perf
-trajectory run over run.
+trajectory run over run. The exit code is non-zero when any module
+failed or reported a parity MISMATCH.
 """
 from __future__ import annotations
 
@@ -85,9 +86,6 @@ def main(argv=None) -> None:
                          "the sole selected module's output, or the "
                          "engine result when several are selected "
                          "(legacy behavior)")
-    ap.add_argument("--strict", action="store_true",
-                    help="exit non-zero if any module FAILED or reported a "
-                         "parity MISMATCH (CI mode)")
     args = ap.parse_args(argv)
     only = set(args.only.split(",")) if args.only else set(MODULES)
     if args.json is not None:
@@ -116,8 +114,7 @@ def main(argv=None) -> None:
         except Exception as e:  # keep the harness going, report the failure
             rows.append(f"{key}_total,0,FAILED:{type(e).__name__}:{e}")
     print("\n".join(rows))
-    if args.strict and any(",FAILED:" in r or r.endswith(",MISMATCH")
-                           for r in rows):
+    if any(",FAILED:" in r or r.endswith(",MISMATCH") for r in rows):
         sys.exit(1)
 
 

@@ -136,7 +136,10 @@ def _hostile_connections(address):
 def _traced_client_proc(address, fingerprint, out_path):
     """Spawn target: a SEPARATE process running one traced cold fit, so
     the merged timeline provably crosses a process boundary. Ships its
-    trace events back through a JSON file (no shared memory)."""
+    trace events back through a JSON file (no shared memory). A client
+    needs no accelerator: it is pinned to the CPU before anything imports
+    jax, so it never claims a chip its parent holds."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from repro.obs.trace import Tracer
     from repro.service.frontend import FitServiceClient
     tracer = Tracer(enabled=True, process_name="client")

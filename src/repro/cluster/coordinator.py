@@ -125,7 +125,12 @@ class ClusterConfig:
     resume: bool = False
     backend: str = "auto"
     limit_threads: bool = True
-    jax_platforms: Optional[str] = None
+    # Workers run on the host CPU: the cluster emulates the paper's
+    # multi-host CPU deployment, and a chip belongs to one process — a
+    # worker left to pick its platform would claim the chip its parent
+    # (or a sibling) holds. The chip paths are the local and shard_map
+    # executors, in one process.
+    jax_platforms: str = "cpu"
     obs_dir: Optional[str] = None        # observability run directory:
                                          # trace.json / metrics.json /
                                          # telemetry.jsonl (DESIGN.md §12)

@@ -82,8 +82,14 @@ def _setup_env(config: dict):
     processes timeshare the host's cores; unbounded per-process XLA/BLAS
     pools thrash, so workers default to single-threaded compute (the
     coordinator overrides via config on big hosts)."""
-    if config.get("jax_platforms"):
-        os.environ["JAX_PLATFORMS"] = config["jax_platforms"]
+    # cpu unless the coordinator says otherwise: one process per chip.
+    # jax is already imported here (repro.cluster imports it eagerly) and
+    # read JAX_PLATFORMS then, so the config is set too; no backend has
+    # started yet, so it still decides.
+    platforms = config.get("jax_platforms") or "cpu"
+    os.environ["JAX_PLATFORMS"] = platforms
+    import jax
+    jax.config.update("jax_platforms", platforms)
     if config.get("limit_threads", True):
         os.environ.setdefault("OMP_NUM_THREADS", "1")
         os.environ.setdefault("OPENBLAS_NUM_THREADS", "1")
@@ -164,16 +170,28 @@ class WorkerRuntime:
         newer link."""
         self._gen += 1
         gen = self._gen
-        self.coord = connect(
-            self.coord_addr, counter=self.counter, chaos=self._conn_chaos,
-            retries=retries,
-            backoff_s=float(self.reconnect.get("backoff_s", 0.5)),
-            backoff_max_s=float(self.reconnect.get("backoff_max_s", 5.0)))
-        self.coord.send("register", wid=self.wid,
-                        peer_addr=self.peers.address,
-                        store_fingerprint=self.store.fingerprint,
-                        pid=os.getpid(),
-                        rejoin=self._registrations > 0)
+        backoff_s = float(self.reconnect.get("backoff_s", 0.5))
+        backoff_max_s = float(self.reconnect.get("backoff_max_s", 5.0))
+        for attempt in range(retries + 1):
+            self.coord = connect(
+                self.coord_addr, counter=self.counter,
+                chaos=self._conn_chaos, retries=retries,
+                backoff_s=backoff_s, backoff_max_s=backoff_max_s)
+            try:
+                self.coord.send("register", wid=self.wid,
+                                peer_addr=self.peers.address,
+                                store_fingerprint=self.store.fingerprint,
+                                pid=os.getpid(),
+                                rejoin=self._registrations > 0)
+                break
+            except ConnectionClosed:
+                # the dial reached a listener on its way out (a crashed
+                # coordinator's socket lingers until its accept thread
+                # lets go, then resets its backlog): dial again
+                self.coord.close()
+                if attempt == retries:
+                    raise
+                time.sleep(min(backoff_s * 2.0 ** attempt, backoff_max_s))
         self._registrations += 1
         threading.Thread(target=self._coord_rx,
                          args=(self.coord, gen), daemon=True).start()

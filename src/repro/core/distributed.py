@@ -138,7 +138,7 @@ class DistributedUnwrappedADMM:
             nshards *= mesh.shape[a]
         # Uneven datasets are zero-padded to a shard multiple rather than
         # rejected: zero rows are EXACT under the transpose reduction
-        # (no Gram, d, or residual contribution — gram.blocked_rows), and
+        # (no Gram, d, or residual contribution), and
         # with zero aux their iterates stay at zero, so the only telemetry
         # they touch is the objective's constant f(0) term, subtracted in
         # the wrapper below.

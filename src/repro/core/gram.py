@@ -6,10 +6,10 @@ matrix by streaming row blocks; one all-reduce produces the global Gram.
 
 Three implementations with identical semantics:
   * ``gram``            — one-shot jnp (oracle / small inputs).
-  * ``gram_chunked``    — lax.scan over row blocks; bounds live memory to one
-                          block, mirrors the HBM->VMEM streaming the Pallas
-                          kernel performs, and is what the distributed fitter
-                          uses under jit (XLA fuses the block matmuls).
+  * ``gram_chunked``    — lax.scan over row blocks (``scan_row_blocks``, which
+                          slices D in place rather than padding a copy);
+                          bounds live memory to one block, mirrors the
+                          HBM->VMEM streaming the Pallas kernel performs.
   * ``repro.kernels.gram.ops.gram`` — the Pallas TPU kernel (VMEM accumulator).
 
 Accumulation is always f32 (or f64 if inputs are f64): the Gram sum is a long
@@ -30,17 +30,10 @@ def _acc_dtype(dtype) -> jnp.dtype:
     return jnp.float64 if dtype == jnp.float64 else jnp.float32
 
 
-def blocked_rows(x: Array, block_rows: int) -> Array:
-    """Zero-pad rows to a block multiple and reshape to
-    (nblocks, block_rows, ...) — the shared scaffold of every streaming
-    row-block reduction here (zero rows contribute nothing to the sums,
-    so the padding is exact; no masking needed)."""
-    m = x.shape[0]
-    nblocks = -(-m // block_rows)
-    pad = nblocks * block_rows - m
-    if pad:
-        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-    return x.reshape((nblocks, block_rows) + x.shape[1:])
+def t_dot(a: Array, b: Array) -> Array:
+    """a^T b at full f32 precision: a TPU's default runs f32 operands as a
+    single bf16 pass, which a long row reduction cannot afford."""
+    return jnp.matmul(a.T, b, precision=jax.lax.Precision.HIGHEST)
 
 
 def gram(D: Array) -> Array:
@@ -55,23 +48,49 @@ def gram_rhs(D: Array, b: Array) -> Array:
     return D.astype(acc).T @ b.astype(acc)
 
 
+def scan_row_blocks(body, init, arrays, block_rows: int):
+    """Fold ``body(carry, blocks) -> (carry, out)`` over consecutive
+    ``block_rows``-row blocks of ``arrays`` (all with the same m rows).
+
+    The shared scaffold of every streaming row-block reduction here. It
+    never pads or copies the arrays: each full block is a dynamic slice of
+    the original, and a ragged tail is one static slice of fewer than
+    ``block_rows`` rows. ``out`` (a pytree of
+    per-row arrays, or None) comes back concatenated to m rows.
+    """
+    m = arrays[0].shape[0]
+    nfull, tail = divmod(m, block_rows)
+    outs = []
+    carry = init
+    if nfull:
+        def step(c, i):
+            return body(c, tuple(
+                jax.lax.dynamic_slice_in_dim(a, i * block_rows, block_rows)
+                for a in arrays))
+
+        carry, full = jax.lax.scan(step, carry, jnp.arange(nfull))
+        outs.append(jax.tree.map(
+            lambda o: o.reshape((nfull * block_rows,) + o.shape[2:]), full))
+    if tail:
+        carry, last = body(carry, tuple(a[nfull * block_rows:]
+                                        for a in arrays))
+        outs.append(last)
+    if len(outs) == 1:
+        return carry, outs[0]
+    return carry, jax.tree.map(lambda *o: jnp.concatenate(o), *outs)
+
+
 @partial(jax.jit, static_argnames=("block_rows",))
 def gram_chunked(D: Array, block_rows: int = 1024) -> Array:
-    """Streaming D^T D over row blocks of size ``block_rows``.
-
-    Rows are zero-padded up to a block multiple — zero rows contribute nothing
-    to the Gram sum, so padding is exact (no masking needed).
-    """
+    """Streaming D^T D over row blocks of size ``block_rows``."""
     m, n = D.shape
     acc = _acc_dtype(D.dtype)
-    Dp = blocked_rows(D, block_rows)
 
     def body(G, blk):
-        blk = blk.astype(acc)
-        return G + blk.T @ blk, None
+        Db = blk[0].astype(acc)
+        return G + t_dot(Db, Db), None
 
-    G0 = jnp.zeros((n, n), acc)
-    G, _ = jax.lax.scan(body, G0, Dp)
+    G, _ = scan_row_blocks(body, jnp.zeros((n, n), acc), (D,), block_rows)
     return G
 
 
@@ -86,17 +105,15 @@ def gram_and_rhs_chunked(
     """
     m, n = D.shape
     acc = _acc_dtype(D.dtype)
-    Dp = blocked_rows(D, block_rows)
-    bp = blocked_rows(b, block_rows)
 
     def body(carry, blk):
         G, c = carry
         Db, bb = blk
         Db = Db.astype(acc)
-        return (G + Db.T @ Db, c + Db.T @ bb.astype(acc)), None
+        return (G + t_dot(Db, Db), c + t_dot(Db, bb.astype(acc))), None
 
     init = (jnp.zeros((n, n), acc), jnp.zeros((n,) + b.shape[1:], acc))
-    (G, c), _ = jax.lax.scan(body, init, (Dp, bp))
+    (G, c), _ = scan_row_blocks(body, init, (D, b), block_rows)
     return G, c
 
 
@@ -109,15 +126,13 @@ def gram_rhs_chunked(D: Array, b: Array, block_rows: int = 1024) -> Array:
     the iteration engine)."""
     m, n = D.shape
     acc = _acc_dtype(D.dtype)
-    Dp = blocked_rows(D, block_rows)
-    bp = blocked_rows(b, block_rows)
 
     def body(c, blk):
         Db, bb = blk
-        return c + Db.astype(acc).T @ bb.astype(acc), None
+        return c + t_dot(Db.astype(acc), bb.astype(acc)), None
 
     c0 = jnp.zeros((n,) + b.shape[1:], acc)
-    c, _ = jax.lax.scan(body, c0, (Dp, bp))
+    c, _ = scan_row_blocks(body, c0, (D, b), block_rows)
     return c
 
 

@@ -24,8 +24,10 @@ in the same stream, and the remaining residual quantities are elementwise:
     s   = tau ||w||,   eps_dual ~ tau ||v||
 
 Data layout: ``D`` is ``(N, m_i, n)`` — N nodes, m_i rows each. N=1 recovers
-the single-node Alg. 1. This module is the *reference semantics*; the
-multi-device version (``repro.core.distributed``) runs the same engine body
+the single-node Alg. 1, and a flat ``(m, n)`` matrix counts as one node (on
+a TPU the flat form is the one whose default layout the kernels read
+without a copy — kernels/tiling.py). This module is the *reference
+semantics*; the multi-device version (``repro.core.distributed``) runs the same engine body
 per shard under ``shard_map`` with a psum where this module sums over rows.
 """
 from __future__ import annotations
@@ -42,6 +44,11 @@ from repro.core.prox import ProxLoss
 from repro.data.sparse import BlockCSR
 
 Array = jax.Array
+
+
+def node_shape(D) -> Tuple[int, int, int]:
+    """(N, m_i, n) of node-stacked data; a flat (m, n) matrix is one node."""
+    return (1,) + tuple(D.shape) if D.ndim == 2 else tuple(D.shape)
 
 
 class ADMMHistory(NamedTuple):
@@ -95,7 +102,7 @@ class UnwrappedADMM:
 
     # -- setup (Alg. 2 lines 2-3): one Gram all-reduce + one factorization --
     def setup(self, D: Array) -> Array:
-        N, mi, n = D.shape
+        N, mi, n = node_shape(D)
         G, _ = self.engine.gram(D.reshape(N * mi, n),
                                 block_rows=self.gram_block_rows)
         ridge = self.rho / self.tau
@@ -107,7 +114,7 @@ class UnwrappedADMM:
         """Single step on node-stacked arrays — the oracle surface kernel
         tests compare against; the drivers below inline the same engine
         body around a carried ``d`` instead of recomputing it."""
-        N, mi, n = D.shape
+        N, mi, n = node_shape(D)
         eng = self.engine
         Dflat = D.reshape(N * mi, n)
         d = eng.transpose_d(Dflat, y.reshape(-1), lam.reshape(-1))
@@ -192,7 +199,7 @@ class UnwrappedADMM:
         x0: Optional[Array] = None,
         record: bool = True,
     ) -> ADMMResult:
-        N, mi, n = D.shape
+        N, mi, n = node_shape(D)
         m = N * mi
         acc = gram_lib._acc_dtype(D.dtype)
         eng = self.engine

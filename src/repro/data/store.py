@@ -12,8 +12,8 @@ Layout contract:
 
   * rows are split into fixed-height blocks of ``block_rows``; the tail
     block is stored UNPADDED (logical length) and zero-padded on read when
-    ``padded=True`` — zero rows are exact under every transpose reduction
-    (``gram.blocked_rows``) so padded reads need no masks;
+    ``padded=True`` — zero rows are exact under every transpose reduction,
+    so padded reads need no masks;
   * ``aux`` (labels / right-hand sides) rides along row-aligned, optional;
   * every block carries a content fingerprint computed at WRITE time, so
     downstream ingestion (``SufficientStats.from_store``) folds the

@@ -8,15 +8,14 @@ and overridable by pinning an entry before the first resolve.
 
 Budget math (see DESIGN.md §7 for the kernel-side derivation):
 
-  * Pallas fused iteration: the live set per grid step is the (bm, n) D
-    panel (double-buffered by the pipeline), the (1, n) x row, three
-    (1, n) f32 accumulators (d, w, v), and five (bm, 1) vector blocks
-    (y, lam, aux in; y', lam' out), also double-buffered. With dsize =
-    bytes per D element:
-        2*bm*n*dsize + 4*n*4 + 10*bm*4  <=  VMEM_BUDGET.
-  * Pallas Gram / Gram+RHS: 2*bm*(bn_i + bn_j)*dsize streamed D panels +
-    bn*bn*4 resident accumulator, plus for the fused RHS the (bn, rpad)
-    resident C block and the double-buffered (bm, rpad) f32 B stream.
+  * Pallas kernels: each kernel module states the tiled VMEM footprint of
+    one grid step (``vmem_bytes``: lanes rounded to 128, sublanes to 8/16,
+    double-buffered panels and the in-register f32 work), calibrated
+    against what the v5e compiler asks for. The row block is the tallest
+    multiple of 128 rows whose footprint fits ``VMEM_BUDGET``, preferring
+    one that divides m so the last panel needs no mask. The kernels pass
+    the footprint to the compiler as ``vmem_limit_bytes`` when it exceeds
+    the 16 MiB default scoped limit.
   * chunked (lax.scan) backend: the same streaming shape on CPU/GPU; the
     budget stands in for the last-level-cache slice a core can keep hot,
     so one block of D plus its vectors stays resident between the Dx and
@@ -28,9 +27,16 @@ from typing import Dict, Tuple
 
 import jax.numpy as jnp
 
-# ~16 MB physical VMEM per TPU core; leave headroom for the pipeline's
-# own scratch and semaphores.
-VMEM_BUDGET = 8 * 1024 * 1024
+from repro.kernels import tiling
+from repro.kernels.admm_iter import admm_iter as admm_iter_kernel
+from repro.kernels.gram import gram as gram_kernel
+
+# Working set one Pallas kernel may plan for. The v5e compiler reports
+# 128 MiB of VMEM per core; 32 MiB leaves room for the pipeline's own
+# scratch and keeps blocks in the range where per-step overhead is small.
+VMEM_BUDGET = 32 * 1024 * 1024
+# Tallest Pallas row block: beyond this a taller panel only adds VMEM.
+MAX_BLOCK_M = 4096
 # Last-level cache slice assumed hot per chunked-backend stream on CPU/GPU.
 CACHE_BUDGET = 2 * 1024 * 1024
 # Default per-block DEVICE-memory budget for the out-of-core streaming
@@ -45,11 +51,6 @@ def _dsize(dtype) -> int:
     return jnp.dtype(dtype).itemsize
 
 
-def _sublane(dtype) -> int:
-    """Minimum second-to-last-dim tile for the dtype (f32: 8, bf16: 16)."""
-    return {4: 8, 2: 16, 1: 32}.get(_dsize(dtype), 8)
-
-
 def _clamp_multiple(value: int, mult: int, lo: int, hi: int) -> int:
     v = max(lo, min(hi, value))
     return max(mult, (v // mult) * mult)
@@ -61,18 +62,29 @@ def _row_cap(m: int, mult: int) -> int:
     return -(-m // mult) * mult
 
 
+def _pallas_block_m(m: int, footprint) -> int:
+    """Tallest lane-aligned row block whose ``footprint(bm)`` fits the
+    budget; all of m when that fits, else a divisor of m when one is at
+    least half as tall (so the last panel needs no mask)."""
+    lane = tiling.LANE
+    bm = lane
+    while bm + lane <= MAX_BLOCK_M and footprint(bm + lane) <= VMEM_BUDGET:
+        bm += lane
+    if m <= bm:
+        return m
+    for cand in range(bm, bm // 2 - 1, -lane):
+        if m % cand == 0:
+            return cand
+    return bm
+
+
 def iter_block_m(m: int, n: int, dtype) -> int:
     """Row-panel height for the fused Pallas iteration kernel."""
     key = ("iter", int(m), int(n), jnp.dtype(dtype).name)
     if key not in CACHE:
-        dsize = _dsize(dtype)
-        # 2*bm*n*dsize (double-buffered panel) + 10*bm*4 (five vector
-        # blocks, double-buffered) + 4*n*4 (x + d/w/v accumulators)
-        # <= budget, solved for bm.
-        bm = (VMEM_BUDGET - 4 * n * 4) // (2 * n * dsize + 40)
-        sub = _sublane(dtype)
-        cap = _row_cap(m, sub)
-        CACHE[key] = (_clamp_multiple(bm, sub, min(128, cap), min(4096, cap)),)
+        fm = tiling.feature_major(m, n, dtype)
+        CACHE[key] = (_pallas_block_m(
+            m, lambda bm: admm_iter_kernel.vmem_bytes(bm, n, dtype, fm)),)
     return CACHE[key][0]
 
 
@@ -80,28 +92,19 @@ def gram_blocks(m: int, n: int, dtype, rhs: int = 0) -> Tuple[int, int]:
     """(block_m, block_n) for the Gram / fused Gram+RHS kernels.
 
     ``rhs`` is the stacked right-hand-side count (0 = Gram only); its
-    lane-padded B stream and resident C block are budgeted so wide
-    multi-RHS ingests shrink bm instead of blowing the VMEM budget.
+    (r, bm) B stream and resident C block are budgeted so wide multi-RHS
+    ingests shrink bm instead of blowing the VMEM budget.
     """
-    rpad = -(-max(rhs, 1) // 128) * 128 if rhs else 0
-    key = ("gram", int(m), int(n), jnp.dtype(dtype).name, rpad)
+    key = ("gram", int(m), int(n), jnp.dtype(dtype).name, int(rhs))
     if key not in CACHE:
-        dsize = _dsize(dtype)
-        # Lane-aligned output tile first: bn >= 256 keeps the kernel
-        # MXU-bound (arithmetic intensity ~ bn FLOP/byte), but never wider
-        # than the (padded) feature count.
-        bn = _clamp_multiple(n, 128, 128, 512)
-        bn = min(bn, 512)
-        # Then the tallest row panel that fits beside the resident bn x bn
-        # accumulator (+ bn x rpad C block), counting the double-buffered
-        # D panels (2 inputs) and the double-buffered f32 B stream.
-        resident = bn * bn * 4 + bn * rpad * 4
-        per_row = 4 * bn * dsize + 2 * rpad * 4
-        bm = (VMEM_BUDGET - resident) // per_row
-        sub = _sublane(dtype)
-        cap = _row_cap(m, sub)
-        CACHE[key] = (_clamp_multiple(bm, sub, min(128, cap), min(2048, cap)),
-                      bn)
+        # Output tile first: all of n up to 512 (one tile, no partial
+        # block), else 512 — bn >= 256 keeps the kernel MXU-bound
+        # (arithmetic intensity ~ bn FLOP/byte).
+        bn = n if n <= 512 else 512
+        fm = tiling.feature_major(m, n, dtype)
+        bm = _pallas_block_m(
+            m, lambda bm: gram_kernel.vmem_bytes(bm, bn, dtype, fm, rhs=rhs))
+        CACHE[key] = (bm, bn)
     return CACHE[key]
 
 

@@ -33,14 +33,19 @@ inlining einsums. The engine owns three interchangeable backends
 ``auto`` resolves per device (TPU -> pallas, else chunked), then falls
 back by capability: Pallas needs a kernel-supported coordinatewise prox
 (logistic / hinge / l1 / least_squares / quantile, f32 or bf16 rows);
-chunked needs a
-coordinatewise prox; everything else lands on reference. bf16 data
-residency (``residency="bf16"``) halves iteration HBM bytes again on top
-of the fused pass — all accumulation stays f32 in-register regardless.
-``residency="auto"`` applies bf16 only where it is a measured win (the
-real-TPU pallas backend); on CPU/chunked backends the per-block upcast
-dominates the saved bytes (BENCH_engine.json: 0.55x/1.88x), so auto
-resolves to None there (DESIGN.md §8).
+chunked needs a coordinatewise prox; everything else lands on reference.
+Only ``auto`` chooses: an explicit ``pallas`` (or ``pallas_interpret``)
+that the loss or dtype cannot run raises instead of quietly running
+another backend. ``pallas_interpret`` is a test mode: ``auto`` never
+resolves to it.
+
+bf16 data residency (``residency="bf16"``) halves iteration HBM bytes
+again on top of the fused pass — all accumulation stays f32 in-register
+regardless. ``residency="auto"`` picks bf16 on the real-TPU pallas
+backend, where the saved bytes should pay (not measured on a chip yet);
+on CPU/chunked backends the per-block upcast costs more than the saved
+bytes (CPU timings in BENCH_engine.json: 0.55x/1.88x), so auto resolves
+to None there (DESIGN.md §8).
 """
 from __future__ import annotations
 
@@ -67,9 +72,10 @@ BACKENDS = ("reference", "chunked", "sparse", "pallas", "pallas_interpret")
 PALLAS_KINDS = frozenset(
     {"logistic", "hinge", "l1", "least_squares", "quantile"})
 
-# "auto" resolves per backend at prepare()-time: bf16 where the HBM-bytes
-# win is real (real-TPU pallas), None on CPU/chunked backends where the
-# per-block upcast is a measured slowdown (DESIGN.md §8).
+# "auto" resolves per backend at prepare()-time: bf16 on real-TPU pallas,
+# where halving the HBM bytes should pay (not measured on a chip), None on
+# CPU/chunked backends where the per-block upcast is a CPU-measured
+# slowdown (DESIGN.md §8).
 RESIDENCY_DTYPES = {None: None, "bf16": jnp.bfloat16, "auto": "auto"}
 
 
@@ -113,11 +119,15 @@ def gram_stats(D: Array, b: Optional[Array] = None, *,
         # sparse setup runs outside jit, like every other store-driven
         # setup pass in the repo.
         return spgram_ops.sparse_gram_rhs(D, b)
-    if backend in ("auto", "sparse"):      # "sparse" is data-format-
-        backend = default_backend()        # selected; dense input streams
+    chosen = backend in ("auto", "sparse")   # "sparse" is data-format-
+    if chosen:                               # selected; dense input streams
+        backend = default_backend()
     m, n = D.shape
     if backend in ("pallas", "pallas_interpret") and D.dtype == jnp.float64:
-        backend = "chunked"          # Pallas kernels are f32/bf16 only
+        if not chosen:
+            raise ValueError(f"backend {backend!r} runs f32/bf16 data, "
+                             f"not {D.dtype}; use backend='auto'")
+        backend = "chunked"
     if backend in ("pallas", "pallas_interpret"):
         interp = backend == "pallas_interpret"
         rhs = 0 if b is None else (b.shape[1] if b.ndim > 1 else 1)
@@ -169,16 +179,21 @@ class IterationEngine:
 
     # -- backend selection (rules documented in DESIGN.md §8) ---------------
     def resolve(self, dtype=jnp.float32) -> str:
-        b = default_backend() if self.backend == "auto" else self.backend
-        if b == "sparse":
-            # "sparse" is a data-format backend: dense arrays have no
-            # sparse body, so a dense resolve lands on the device default
-            # (the format dispatch in iterate() picks sparse for BlockCSR
-            # under every backend except an explicit reference).
-            b = default_backend()
+        # "sparse" is a data-format backend: dense arrays have no sparse
+        # body, so a dense resolve lands on the device default (the format
+        # dispatch in iterate() picks sparse for BlockCSR under every
+        # backend except an explicit reference).
+        chosen = self.backend in ("auto", "sparse")
+        b = default_backend() if chosen else self.backend
         if b in ("pallas", "pallas_interpret") and (
                 self.loss.name not in PALLAS_KINDS
                 or jnp.dtype(dtype) == jnp.float64):
+            if not chosen:
+                raise ValueError(
+                    f"backend {b!r} cannot run loss {self.loss.name!r} on "
+                    f"{jnp.dtype(dtype).name} data (its kernel takes "
+                    f"{sorted(PALLAS_KINDS)} on f32/bf16); use "
+                    "backend='auto' to let the engine choose")
             b = "chunked"
         if b == "chunked" and not self.loss.coordinatewise:
             b = "reference"
@@ -187,9 +202,10 @@ class IterationEngine:
     def resolve_residency(self, dtype=jnp.float32) -> Optional[str]:
         """DESIGN.md §8 residency rule: explicit settings are honored
         as-is; ``"auto"`` casts to bf16 only on the real-TPU pallas
-        backend — on CPU/chunked (and interpret-mode) backends the
-        per-block upcast dominates the saved bytes (measured 0.55x/1.88x
-        vs 4.89x in BENCH_engine.json), so auto resolves to None."""
+        backend (not measured on a chip) — on CPU/chunked (and
+        interpret-mode) backends the per-block upcast dominates the saved
+        bytes (CPU timings 0.55x/1.88x in BENCH_engine.json), so auto
+        resolves to None."""
         if self.residency != "auto":
             return self.residency
         return "bf16" if self.resolve(dtype) == "pallas" else None
@@ -216,8 +232,8 @@ class IterationEngine:
         return gram_stats(D, b, backend=backend, block_rows=block_rows)
 
     def _gram_backend(self, dtype) -> str:
-        b = default_backend() if self.backend == "auto" else self.backend
-        return "chunked" if b == "reference" else b
+        # "auto" passes through so gram_stats may choose by dtype
+        return "chunked" if self.backend == "reference" else self.backend
 
     # -- transpose application: D^T u without a dense upcast ----------------
     def rmatvec(self, D, u: Array) -> Array:
@@ -300,11 +316,7 @@ class IterationEngine:
         acc = gram_lib._acc_dtype(D.dtype)
         br = self.block_m or autotune.chunked_block_rows(m, n, D.dtype)
         xc = x.astype(acc)
-        blocks = [gram_lib.blocked_rows(D, br),
-                  gram_lib.blocked_rows(y, br),
-                  gram_lib.blocked_rows(lam, br)]
-        if aux is not None:
-            blocks.append(gram_lib.blocked_rows(aux, br))
+        arrays = (D, y, lam) + ((aux,) if aux is not None else ())
 
         def body(carry, blk):
             d, w, v = carry
@@ -320,9 +332,9 @@ class IterationEngine:
             return (d, w, v), (y_b, l_b)
 
         zero = jnp.zeros((n,), acc)
-        (d, w, v), (ys, ls) = jax.lax.scan(
-            body, (zero, zero, zero), tuple(blocks))
-        return EngineStep(ys.reshape(-1)[:m], ls.reshape(-1)[:m], d,
+        (d, w, v), (ys, ls) = gram_lib.scan_row_blocks(
+            body, (zero, zero, zero), arrays, br)
+        return EngineStep(ys, ls, d,
                           w if want_dual else None,
                           v if want_dual else None)
 

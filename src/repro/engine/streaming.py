@@ -20,7 +20,7 @@ Host-resident iterate contract: ``y`` and ``lam`` live in caller-owned
 (m,) numpy arrays, mutated in place block-by-block each sweep; only the
 n-sized reductions (d, w, v) and the stopping-rule scalars stay on the
 device between sweeps. Tail-block padding is exact (zero D rows
-contribute nothing to any reduction — ``gram.blocked_rows``); the one
+contribute nothing to any reduction); the one
 non-exact quantity, the objective's value on pad rows, is a constant
 (pad iterates stay at zero) subtracted once at setup.
 """

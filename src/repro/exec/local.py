@@ -3,8 +3,8 @@
 Replaces the two hand-rolled ``lax.while_loop`` drivers that used to
 live in ``core/unwrapped.py`` (``_solve_dense`` / ``_solve_sparse``):
 one jitted fused step per iteration, the loop itself in the shared
-driver. Accepts node-stacked dense (N, m_i, n) arrays or a flat
-:class:`~repro.data.sparse.BlockCSR`.
+driver. Accepts node-stacked dense (N, m_i, n) arrays, a flat dense
+(m, n) matrix (one node), or a flat :class:`~repro.data.sparse.BlockCSR`.
 """
 from __future__ import annotations
 
@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import gram as gram_lib
+from repro.core.unwrapped import node_shape
 from repro.data.sparse import BlockCSR
 from repro.engine.streaming import SweepResult
 from repro.exec.base import SolveExecutor
@@ -35,7 +36,7 @@ class LocalExecutor(SolveExecutor):
             self._stack = None               # y comes back as (1, m)
             self._Dflat = D
         else:
-            N, mi, n = D.shape
+            N, mi, n = node_shape(D)
             self.m, self.n = N * mi, n
             self._stack = (N, mi)
             self._Dflat = D.reshape(self.m, n)

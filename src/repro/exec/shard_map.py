@@ -8,7 +8,8 @@ Rows are zero-padded host-side to a shard multiple (exact: zero rows
 contribute nothing to any reduction); y/lam live on-device as sharded
 arrays between sweeps, and only n-sized reductions (one psum per
 quantity, optionally int8 error-feedback compressed for d) come back
-replicated.
+replicated. Device arrays whose rows already divide evenly are placed
+as they are, never through the host.
 """
 from __future__ import annotations
 
@@ -45,29 +46,33 @@ class ShardMapExecutor(SolveExecutor):
         self.engine = engine
         self.axes = tuple(data_axes)
         self.mesh = mesh if mesh is not None else default_mesh(self.axes)
-        D = np.asarray(D)
+        nshards = 1
+        for a in self.axes:
+            nshards *= self.mesh.shape[a]
+        self.nshards = nshards
         if D.ndim == 3:                    # node-stacked convention
             D = D.reshape(-1, D.shape[-1])
         self.m, self.n = D.shape
+        self.pad = -(-self.m // nshards) * nshards - self.m
+        if isinstance(D, jax.Array) and not self.pad:
+            # device data (launch.fit generates it row-sharded) stays on
+            # the devices: placing it is a no-op when already sharded so
+            pad_rows = lambda a: a
+        else:
+            D = np.asarray(D)
+            pad_rows = lambda a: np.pad(
+                np.asarray(a), ((0, self.pad),) + ((0, 0),) * (a.ndim - 1))
         self.ycols = getattr(engine.loss, "ycols", 1)
         self.acc = gram_lib._acc_dtype(D.dtype)
         self.backend = engine.resolve(D.dtype)
         # int8 EF compression quantizes flat n-vectors; matrix-valued d
         # (multinomial) falls back to the plain psum
         self.compress = bool(compress) and self.ycols == 1
-        nshards = 1
-        for a in self.axes:
-            nshards *= self.mesh.shape[a]
-        self.nshards = nshards
-        self.pad = -(-self.m // nshards) * nshards - self.m
-        Dp = np.pad(D, ((0, self.pad), (0, 0)))
-        self._D = shard_rows(self.mesh, Dp, self.axes)
+        self._D = shard_rows(self.mesh, pad_rows(D), self.axes)
+        self._aux = None
         if aux is not None:
-            aux = np.asarray(aux).reshape(self.m)
-            self._aux = shard_rows(self.mesh, np.pad(aux, (0, self.pad)),
+            self._aux = shard_rows(self.mesh, pad_rows(aux.reshape(self.m)),
                                    self.axes)
-        else:
-            self._aux = None
         self.has_aux = aux is not None
         self._y = None
         self._lam = None

@@ -1,23 +1,22 @@
-"""Jitted wrappers for the fused ADMM-iteration kernel (pads rows; zero-pad
-rows contribute nothing to d/w/v since their y', lam' and y' - y are forced
-to 0 via aux=0/y=0/lam=0/D=0 rows: prox(0)=0 for every supported kind at
-z=0 with l=0)."""
+"""Jitted wrappers for the fused ADMM-iteration kernel. Nothing here copies
+D: a ragged last row block is masked inside the kernel, and the panel
+orientation follows D's layout in HBM (kernels/tiling.py)."""
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 
+from repro.kernels import tiling
 from repro.kernels.admm_iter.admm_iter import admm_iter_pallas
 
 
 @functools.partial(
     jax.jit, static_argnames=("kind", "delta", "block_m", "interpret",
-                              "param"))
+                              "param", "feature_major"))
 def admm_iter_full(D, aux, y, lam, x, *, kind: str, delta: float,
                    block_m: int = 1024, interpret: bool = False,
-                   param: float = 0.0):
+                   param: float = 0.0, feature_major=None):
     """Fused iteration body returning (y', lam', d, w, v).
 
     d = D^T(y' - lam') feeds the next x-update (paper Alg. 2 line 6);
@@ -25,27 +24,14 @@ def admm_iter_full(D, aux, y, lam, x, *, kind: str, delta: float,
     tolerance without a second pass over D (the engine's one-pass
     telemetry — DESIGN.md §8). Differences are formed in-register before
     the reduction, so the residuals keep full f32 accuracy near
-    convergence.
+    convergence. ``block_m`` at or above m means one block of all rows;
+    ``feature_major`` None follows the device's default layout of D.
     """
     m, n = D.shape
-    pad = (-m) % block_m
-    if pad:
-        D = jnp.pad(D, ((0, pad), (0, 0)))
-        aux = jnp.pad(aux, (0, pad))
-        y = jnp.pad(y, (0, pad))
-        lam = jnp.pad(lam, (0, pad))
-    y_new, lam_new, d, w, v = admm_iter_pallas(
-        D, aux, y, lam, x, kind=kind, delta=delta, block_m=block_m,
+    if feature_major is None:
+        feature_major = tiling.feature_major(m, n, D.dtype)
+    return admm_iter_pallas(
+        D, aux, y, lam, x, kind=kind, delta=delta,
+        block_m=min(block_m, m), feature_major=feature_major,
         interpret=interpret, param=param)
-    return y_new[:m], lam_new[:m], d, w, v
 
-
-@functools.partial(
-    jax.jit, static_argnames=("kind", "delta", "block_m", "interpret"))
-def admm_iter(D, aux, y, lam, x, *, kind: str, delta: float,
-              block_m: int = 1024, interpret: bool = False):
-    """Back-compat 3-tuple surface: (y', lam', d)."""
-    y_new, lam_new, d, _, _ = admm_iter_full(
-        D, aux, y, lam, x, kind=kind, delta=delta, block_m=block_m,
-        interpret=interpret)
-    return y_new, lam_new, d

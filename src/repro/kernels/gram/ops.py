@@ -1,7 +1,8 @@
-"""Jitted public wrappers for the Gram kernels: padding, symmetry restore,
-the fused Gram+RHS kernel (``gram_and_rhs`` — D^T D and D^T B accumulated in
-the same row stream; the engine's setup path), the legacy append-column
-trick (``gram_with_rhs``), and interpret-mode fallback for CPU."""
+"""Jitted public wrappers for the Gram kernels: block clamping, symmetry
+restore, and the fused Gram+RHS kernel (``gram_and_rhs`` — D^T D and D^T B
+accumulated in the same row stream; the engine's setup path). Nothing here
+copies D: ragged blocks are handled inside the kernels, and the panel
+orientation follows D's layout in HBM (kernels/tiling.py)."""
 from __future__ import annotations
 
 import functools
@@ -9,21 +10,22 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import tiling
 from repro.kernels.gram.gram import gram_pallas, gram_rhs_pallas
 
-def _pad_to(x, mult, axis):
-    size = x.shape[axis]
-    pad = (-size) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
+
+def _blocks(D, block_m, block_n, feature_major):
+    """Clamp blocks to the array (a block at or above a dimension covers
+    all of it) and resolve the panel orientation."""
+    m, n = D.shape
+    if feature_major is None:
+        feature_major = tiling.feature_major(m, n, D.dtype)
+    return min(block_m, m), min(block_n, n), feature_major
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_m", "block_n", "symmetric_skip", "interpret")
-)
+    jax.jit, static_argnames=("block_m", "block_n", "symmetric_skip",
+                              "interpret", "feature_major"))
 def gram(
     D: jax.Array,
     *,
@@ -31,23 +33,15 @@ def gram(
     block_n: int = 256,
     symmetric_skip: bool = True,
     interpret: bool = False,
+    feature_major=None,
 ) -> jax.Array:
-    """D^T D, f32, any (m, n) — pads to block multiples (exact for Gram)."""
-    m, n = D.shape
-    Dp = _pad_to(_pad_to(D, block_m, 0), block_n, 1)
-    G = gram_pallas(
-        Dp,
-        block_m=block_m,
-        block_n=block_n,
-        symmetric_skip=symmetric_skip,
-        interpret=interpret,
-    )
+    """D^T D, f32, any (m, n)."""
+    bm, bn, fm = _blocks(D, block_m, block_n, feature_major)
+    G = gram_pallas(D, block_m=bm, block_n=bn, feature_major=fm,
+                    symmetric_skip=symmetric_skip, interpret=interpret)
     if symmetric_skip:
-        # Mirror the computed upper-triangular blocks. Using block-level skip,
-        # every full block strictly below the diagonal is garbage; rebuild
-        # from the upper triangle (element-wise: the diagonal blocks are full).
-        G = _mirror_upper(G, block_n)
-    return G[:n, :n]
+        G = _mirror_upper(G, bn)
+    return G
 
 
 def _mirror_upper(G: jax.Array, block_n: int) -> jax.Array:
@@ -58,8 +52,8 @@ def _mirror_upper(G: jax.Array, block_n: int) -> jax.Array:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_m", "block_n", "interpret")
-)
+    jax.jit, static_argnames=("block_m", "block_n", "interpret",
+                              "feature_major"))
 def gram_and_rhs(
     D: jax.Array,
     b: jax.Array,
@@ -67,52 +61,19 @@ def gram_and_rhs(
     block_m: int = 512,
     block_n: int = 256,
     interpret: bool = False,
+    feature_major=None,
 ):
     """Fused (D^T D, D^T b) — ONE row stream over D, any (m, n).
 
     ``b`` may be (m,) — the classic lasso rhs — or (m, r) stacked
     right-hand sides (multi-probe serving); c comes back (n,) or (n, r).
-    Pads rows to block_m, features to block_n and rhs lanes to 128 (zero
-    rows/columns are exact for both sums); mirrors the symmetric-skip
-    upper triangle like ``gram``.
+    The right-hand sides enter as lane-dense (r, m) rows: for r = 1 that
+    is a bitcast of b, for r > 1 a transpose of the (small) B.
     """
-    m, n = D.shape
     squeeze = b.ndim == 1
-    B = b[:, None] if squeeze else b
-    r = B.shape[1]
-    Dp = _pad_to(_pad_to(D, block_m, 0), block_n, 1)
-    Bp = _pad_to(_pad_to(B.astype(jnp.float32), block_m, 0), 128, 1)
-    G, C = gram_rhs_pallas(
-        Dp, Bp,
-        block_m=block_m,
-        block_n=block_n,
-        symmetric_skip=True,
-        interpret=interpret,
-    )
-    G = _mirror_upper(G, block_n)[:n, :n]
-    C = C[:n, :r]
-    return G, (C[:, 0] if squeeze else C)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_m", "block_n", "interpret")
-)
-def gram_with_rhs(
-    D: jax.Array,
-    b: jax.Array,
-    *,
-    block_m: int = 512,
-    block_n: int = 256,
-    interpret: bool = False,
-):
-    """One-pass (D^T D, D^T b) by appending b as column n (paper §4 setup)."""
-    m, n = D.shape
-    Db = jnp.concatenate([D, b[:, None].astype(D.dtype)], axis=1)
-    G = gram(
-        Db,
-        block_m=block_m,
-        block_n=block_n,
-        symmetric_skip=True,
-        interpret=interpret,
-    )
-    return G[:n, :n], G[:n, n]
+    Bt = (b[None] if squeeze else b.T).astype(jnp.float32)
+    bm, bn, fm = _blocks(D, block_m, block_n, feature_major)
+    G, Ct = gram_rhs_pallas(D, Bt, block_m=bm, block_n=bn, feature_major=fm,
+                            symmetric_skip=True, interpret=interpret)
+    G = _mirror_upper(G, bn)
+    return G, (Ct[0] if squeeze else Ct.T)

@@ -37,6 +37,10 @@ from repro.runtime.steps import make_serve_step, make_train_step
 from repro.sharding import specs as spec_lib
 from repro.sharding.util import DP, filter_spec
 
+# The chip the roofline terms model: the dry run compiles on host devices,
+# so the kind is named here rather than read from jax.devices().
+MODELED_KIND = "TPU v5 lite"
+
 ARCHES = [
     "arctic-480b", "olmoe-1b-7b", "rwkv6-1.6b", "qwen3-14b",
     "command-r-35b", "phi3-medium-14b", "qwen3-8b",
@@ -214,7 +218,8 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, out_dir: Path,
 
     flops = costs["flops"]
     hbm_bytes = costs["hbm_bytes"]
-    terms = roofline_terms(flops, hbm_bytes, costs["wire_bytes"])
+    terms = roofline_terms(flops, hbm_bytes, costs["wire_bytes"],
+                           MODELED_KIND)
     model_flops = 6.0 * cfg.active_param_count() \
         * SHAPES[shape]["batch"] * SHAPES[shape]["seq"]
     if SHAPES[shape]["kind"] == "decode":
@@ -275,7 +280,8 @@ def run_fit_cell(name: str, *, multi_pod: bool, out_dir: Path, tag: str = ""):
             coll = parse_collectives(compiled.as_text())
             flops = float(cost.get("flops", 0.0))
             hbm = float(cost.get("bytes accessed", 0.0))
-            terms = roofline_terms(flops, hbm, coll.wire_bytes)
+            terms = roofline_terms(flops, hbm, coll.wire_bytes,
+                                   MODELED_KIND)
             result[phase] = {
                 "compile_s": round(time.time() - t0, 1),
                 "flops": flops, "hbm_bytes": hbm,

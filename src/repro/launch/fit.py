@@ -37,19 +37,16 @@ from __future__ import annotations
 import argparse
 import time
 import warnings
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.fit import FitResult, fit as fit_glm
-from repro.core.oracles import (
-    lasso_kkt_gap,
-    logistic_objective,
-    svm_objective,
-)
 from repro.core.prox import make_hinge, make_logistic
 from repro.data import synthetic
+from repro.launch.jax_cache import use_compile_cache
 from repro.obs import Observability
 
 
@@ -236,7 +233,7 @@ def _fit_sparse(args, bcsr, aux, mu, obs=None):
                      "transpose", args.problem)
 
 
-def main(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--problem", default="logistic",
                     choices=["lasso", "logistic", "svm", "sparse_logistic"])
@@ -337,12 +334,16 @@ def main(argv=None):
             args.executor = "local"
     if args.executor == "cluster" and not args.cluster:
         args.cluster = args.workers
+    return args
 
-    key = jax.random.PRNGKey(args.seed)
+
+def main(argv=None):
+    args = parse_args(argv)
+    use_compile_cache()
     N, mi, n = args.nodes, args.rows_per_node, args.features
-    het = 1.0 if args.heterogeneous else 0.0
     t0 = time.time()
     sparse_input = False
+    mesh = None
     if args.density is not None:
         from repro.data import sparse as sparse_data
         m = N * mi
@@ -366,18 +367,22 @@ def main(argv=None):
               f"{args.density} -> {args.sparse_format} "
               f"({gib:.3f} GiB) in {time.time()-t0:.1f}s", flush=True)
     else:
-        if args.problem == "lasso":
-            prob = synthetic.lasso_problem(key, N, mi, n, heterogeneity=het)
-            D, aux = prob.D, prob.b
-            mu = args.mu if args.mu is not None else float(prob.mu)
-        else:
-            prob = synthetic.classification_problem(key, N, mi, n,
-                                                    heterogeneity=het)
-            D, aux = prob.D, prob.labels
-            mu = args.mu if args.mu is not None else 1.0
-        t_data = time.time() - t0
+        if args.executor == "shard_map":
+            from repro.exec.shard_map import default_mesh
+            mesh = default_mesh()
+        D, aux, mu_data = generate_dense(args, mesh)
+        jax.block_until_ready(D)
+        mu = args.mu if args.mu is not None else float(mu_data)
+        layout = "flat" if D.ndim == 2 else "node-stacked"
         print(f"data: {N} nodes x {mi} rows x {n} features "
-              f"({N*mi*n*4/2**30:.2f} GiB) in {t_data:.1f}s", flush=True)
+              f"({N*mi*n*4/2**30:.2f} GiB, {layout}) generated on "
+              f"{D.devices().pop().platform} in {time.time()-t0:.1f}s",
+              flush=True)
+    backend = _engine_backend(args, sparse_input)
+    dev = jax.devices()[0]
+    print(f"engine: backend={backend} device={dev.platform} "
+          f"kind={dev.device_kind!r} count={len(jax.devices())}",
+          flush=True)
 
     # one Observability bundle per run: the cluster path hands the run
     # directory to the coordinator instead (it owns the merged trace),
@@ -398,15 +403,13 @@ def main(argv=None):
     elif args.executor == "shard_map" and args.method == "transpose" \
             and args.problem in ("logistic", "svm"):
         # the shard_map SolveExecutor under the shared driver: the same
-        # stopping rule / telemetry as every other topology, devices
-        # discovered from the default mesh
+        # stopping rule / telemetry as every other topology; D arrives
+        # already row-sharded over the default mesh
         from repro.engine import IterationEngine
         from repro.exec import ShardMapExecutor, solve_with_executor
         loss, rho, tau, _ = _admm_params(args.problem)
-        m = N * mi
-        ex = ShardMapExecutor(IterationEngine(loss=loss, tau=tau),
-                              np.asarray(D.reshape(m, n)),
-                              aux=np.asarray(aux.reshape(m)))
+        ex = ShardMapExecutor(IterationEngine(loss=loss, tau=tau), D,
+                              aux=aux, mesh=mesh)
         r = solve_with_executor(ex, loss=loss, tau=tau, rho=rho,
                                 max_iters=args.iters, record=True,
                                 obs=obs)
@@ -421,6 +424,7 @@ def main(argv=None):
         if obs.enabled and getattr(res.objective, "ndim", None) == 1:
             for i, o in enumerate(np.asarray(res.objective)):
                 obs.record(iter=i + 1, objective=float(o))
+    jax.block_until_ready(res.x)
     dt = time.time() - t0
     obs.finish()
     if args.obs_dir:
@@ -428,42 +432,92 @@ def main(argv=None):
               "telemetry.jsonl)", flush=True)
     print(f"[{args.method}] {args.problem}: {res.iters} iters in {dt:.1f}s",
           flush=True)
+    _print_diagnostics(args, D, aux, np.asarray(res.x), mu, sparse_input)
+    return FitRun(res, backend, dt)
 
-    x = np.asarray(res.x)
-    a2 = np.asarray(aux).reshape(-1)
-    if sparse_input:
-        # O(nnz) diagnostics: everything below needs only Dx / D^T r.
-        from repro.kernels.spgram import ops as spgram_ops
-        Dx = np.asarray(spgram_ops.matvec(D, jnp.asarray(x)))
+
+class FitRun(NamedTuple):
+    result: FitResult
+    backend: str       # engine backend the solve resolved to
+    seconds: float     # solve wall time, compilation included
+
+
+def generate_dense(args, mesh=None):
+    """(D, aux, mu) generated from ``--seed`` on the device in one jitted
+    program, so the elementwise steps fuse and the peak stays near two
+    copies of D. Transpose-method data comes back flat (m, n): on a TPU
+    that is the layout the kernels read without a copy, which a
+    node-stacked (N, m_i, n) array's is not (kernels/tiling.py). With
+    ``mesh`` the rows come back sharded over its devices; with as many
+    nodes as devices each chip generates only its own node."""
+    N, mi, n = args.nodes, args.rows_per_node, args.features
+    het = 1.0 if args.heterogeneous else 0.0
+    flat = args.method == "transpose"
+
+    def gen(key):
         if args.problem == "lasso":
-            grad = np.asarray(spgram_ops.rmatvec(
-                D, jnp.asarray(Dx - a2)))
-            on = np.abs(x) > 1e-7
-            viol = max(float(np.abs(grad[on] + mu * np.sign(x[on])).max())
-                       if on.any() else 0.0,
-                       float(np.maximum(np.abs(grad[~on]) - mu, 0).max())
-                       if (~on).any() else 0.0)
-            print(f"KKT violation: {viol:.2e}, support: {int(on.sum())}")
-        elif args.problem == "logistic":
-            obj = float(np.sum(np.logaddexp(0.0, -a2 * Dx)))
-            acc = float(np.mean(np.sign(Dx) == a2))
-            print(f"objective: {obj:.2f}, train acc: {acc:.4f}")
+            p = synthetic.lasso_problem(key, N, mi, n, heterogeneity=het)
+            D, aux, mu = p.D, p.b, p.mu
         else:
-            obj = float(np.sum(np.maximum(1.0 - a2 * Dx, 0.0))
-                        + 0.5 * np.sum(x * x))
-            print(f"objective: {obj:.2f}")
-        return res
-    D2 = np.asarray(D.reshape(-1, n))
+            p = synthetic.classification_problem(key, N, mi, n,
+                                                 heterogeneity=het)
+            D, aux, mu = p.D, p.labels, jnp.float32(1.0)
+        if flat:
+            D, aux = D.reshape(N * mi, n), aux.reshape(N * mi)
+        return D, aux, mu
+
+    out = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        rows = P(mesh.axis_names)
+        out = (NamedSharding(mesh, rows), NamedSharding(mesh, rows),
+               NamedSharding(mesh, P()))
+    return jax.jit(gen, out_shardings=out)(jax.random.PRNGKey(args.seed))
+
+
+def _engine_backend(args, sparse_input: bool) -> str:
+    """The engine backend this run's data passes resolve to."""
+    from repro.engine import IterationEngine, default_backend
+    if sparse_input:
+        return "sparse"
+    if args.method == "transpose" and args.problem in ("logistic", "svm"):
+        loss = _admm_params(args.problem)[0]
+        return IterationEngine(loss=loss).resolve(jnp.float32)
+    return default_backend()     # Gram-path setup on the device default
+
+
+def _print_diagnostics(args, D, aux, x, mu, sparse_input: bool):
+    """Objective / KKT lines from Dx and D^T r alone, computed where D
+    lives (full f32 precision on a TPU), so no copy of D leaves it."""
+    a2 = np.asarray(aux, np.float64).reshape(-1)
+    if sparse_input:
+        from repro.kernels.spgram import ops as spgram_ops
+        matvec = lambda v: spgram_ops.matvec(D, jnp.asarray(v, jnp.float32))
+        rmatvec = lambda r: spgram_ops.rmatvec(D, jnp.asarray(r,
+                                                              jnp.float32))
+    else:
+        D2 = D.reshape(-1, D.shape[-1])
+        hi = jax.lax.Precision.HIGHEST
+        matvec = lambda v: jnp.dot(D2, jnp.asarray(v, D2.dtype),
+                                   precision=hi)
+        rmatvec = lambda r: jnp.dot(jnp.asarray(r, D2.dtype), D2,
+                                    precision=hi)
+    Dx = np.asarray(matvec(x), np.float64)
     if args.problem == "lasso":
-        viol, sup = lasso_kkt_gap(D2, a2, x, mu)
+        corr = np.asarray(rmatvec(Dx - a2), np.float64)
+        viol = max(float(np.max(np.abs(corr))) - mu, 0.0)
+        on = np.abs(x) > 1e-7
+        sup = (float(np.max(np.abs(corr[on] + mu * np.sign(x[on]))))
+               if on.any() else 0.0)
         print(f"KKT violation: {viol:.2e}, support err: {sup:.2e}")
     elif args.problem in ("logistic", "sparse_logistic"):
-        obj = logistic_objective(D2, a2, x)
-        acc = float(np.mean(np.sign(D2 @ x) == a2))
+        obj = float(np.sum(np.logaddexp(0.0, -a2 * Dx)))
+        acc = float(np.mean(np.sign(Dx) == a2))
         print(f"objective: {obj:.2f}, train acc: {acc:.4f}")
     else:
-        print(f"objective: {svm_objective(D2, a2, x, 1.0):.2f}")
-    return res
+        obj = float(np.sum(np.maximum(1.0 - a2 * Dx, 0.0))
+                    + 0.5 * np.sum(x * x))
+        print(f"objective: {obj:.2f}")
 
 
 if __name__ == "__main__":

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.fit import fit
+from repro.launch.jax_cache import use_compile_cache
 from repro.service import FitRequest, FitServer
 from repro.service.batching import lasso_mu_path
 
@@ -160,6 +161,7 @@ def main(argv=None):
                           "telemetry.jsonl + flight-recorder incidents "
                           "into this run directory")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.port is not None:
         return _serve_networked(args)
@@ -202,8 +204,7 @@ def main(argv=None):
 
     # naive lower bound: one request through the one-shot fit() path
     t0 = time.time()
-    fit(args.problem, D.reshape(1, m, n), reqs[0].b.reshape(1, m),
-        mu=args.mu, iters=args.iters)
+    fit(args.problem, D, reqs[0].b, mu=args.mu, iters=args.iters)
     t_single = time.time() - t0
 
     print(f"served {args.requests} {args.problem} requests in {dt:.2f}s "
@@ -211,7 +212,10 @@ def main(argv=None):
     print(f"one-shot fit() of a single request: {t_single:.2f}s -> naive "
           f"serial estimate {t_single*args.requests:.1f}s, "
           f"speedup ~{t_single*args.requests/max(dt, 1e-9):.0f}x")
-    print("counters:", srv.counters.snapshot())
+    counters = srv.counters.snapshot()
+    print("counters:", counters)
+    return {"statuses": [r.status for r in resp], "counters": counters,
+            "seconds": dt}
 
 
 if __name__ == "__main__":

@@ -13,8 +13,8 @@ Accounting (per device, ring algorithm):
 plus the raw operand-size sum (the assignment's simpler metric) — both are
 reported; the time term uses the ring wire bytes.
 
-Hardware constants (TPU v5e, per assignment): 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware constants: :data:`PEAKS`, keyed by ``device_kind`` as JAX
+reports it. A kind that is not in the table is an error, not a default.
 """
 from __future__ import annotations
 
@@ -22,9 +22,21 @@ import dataclasses
 import re
 from typing import Dict, List, Tuple
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link
+# Per-chip peaks. TPU v5e ("TPU v5 lite"): Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of ICI per
+# chip, i.e. 50 GB/s on each of its four links.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table row for ``device_kind``; raises for an unknown kind."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peaks for device kind {device_kind!r}; "
+                         f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -112,11 +124,14 @@ def parse_collectives(hlo_text: str) -> CollectiveStats:
 
 
 def roofline_terms(flops_per_device: float, hbm_bytes_per_device: float,
-                   wire_bytes_per_device: float) -> Dict[str, float]:
-    """Three per-device time terms (seconds) + the dominant bottleneck."""
-    t_compute = flops_per_device / PEAK_FLOPS
-    t_memory = hbm_bytes_per_device / HBM_BW
-    t_collective = wire_bytes_per_device / LINK_BW
+                   wire_bytes_per_device: float,
+                   device_kind: str) -> Dict[str, float]:
+    """Three per-device time terms (seconds) on ``device_kind``'s peaks +
+    the dominant bottleneck."""
+    pk = peaks(device_kind)
+    t_compute = flops_per_device / pk["flops"]
+    t_memory = hbm_bytes_per_device / pk["hbm_bw"]
+    t_collective = wire_bytes_per_device / pk["link_bw"]
     terms = {"compute_s": t_compute, "memory_s": t_memory,
              "collective_s": t_collective}
     dom = max(terms, key=terms.get)

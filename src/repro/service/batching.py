@@ -51,15 +51,13 @@ def rhs_chunked(D: Array, B: Array, block_rows: int = 1024) -> Array:
     """
     m, n = D.shape
     acc = gram_lib._acc_dtype(D.dtype)
-    Dp = gram_lib.blocked_rows(D, block_rows)
-    Bp = gram_lib.blocked_rows(B, block_rows)
 
     def body(C, blk):
         Db, Bb = blk
-        return C + Db.astype(acc).T @ Bb.astype(acc), None
+        return C + gram_lib.t_dot(Db.astype(acc), Bb.astype(acc)), None
 
     C0 = jnp.zeros((n, B.shape[1]), acc)
-    C, _ = jax.lax.scan(body, C0, (Dp, Bp))
+    C, _ = gram_lib.scan_row_blocks(body, C0, (D, B), block_rows)
     return C
 
 

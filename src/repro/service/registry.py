@@ -33,7 +33,7 @@ from repro.core import fasta as fasta_lib
 from repro.core import gram as gram_lib
 from repro.core import prox as prox_lib
 from repro.core.oracles import default_tau
-from repro.core.unwrapped import UnwrappedADMM
+from repro.core.unwrapped import UnwrappedADMM, node_shape
 from repro.engine import gram_stats
 
 Array = jax.Array
@@ -99,7 +99,7 @@ def solve(problem: str, D: Array, aux: Array, method: str = "transpose",
     spec = get_solver(problem, method)
     if params.get("tau") is None and problem in (
             "lasso", "logistic", "svm", "sparse_logistic", "huber"):
-        N, mi, n = D.shape
+        N, mi, n = node_shape(D)
         base = {"sparse_logistic": "logistic", "huber": "svm"}.get(
             problem, problem)
         params["tau"] = default_tau(base, N * mi)
@@ -172,7 +172,7 @@ def nnls_from_stats(G: Array, c: Array, iters: int = 2000,
 # ---------------------------------------------------------------------------
 
 def _flatten(D: Array):
-    N, mi, n = D.shape
+    N, mi, n = node_shape(D)
     return D.reshape(N * mi, n), N * mi, n
 
 

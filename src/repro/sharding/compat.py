@@ -1,97 +1,51 @@
-"""JAX version compatibility for the mesh / shard_map APIs.
+"""The mesh / shard_map spellings the repo uses, in one place.
 
-The repo is written against the modern explicit-mesh surface
-(``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``,
-``jax.sharding.get_abstract_mesh``, ``jax.shard_map``). Older jaxlibs
-(<= 0.4.x) predate all four; this module is the single place that knows
-both spellings so every other file can stay on the modern one:
+The installed JAX has the explicit-mesh surface (``jax.make_mesh(...,
+axis_types=...)``, ``jax.set_mesh``, ``jax.sharding.get_abstract_mesh``,
+``jax.shard_map``); these helpers pin the choices every caller shares:
 
-  * :func:`make_mesh`     — Auto axis_types when the installed JAX has them.
-  * :func:`use_mesh`      — context manager; ``jax.set_mesh`` or the legacy
-                            ``with mesh:`` thread-resources context.
-  * :func:`current_mesh`  — the ambient (abstract or physical) mesh, or an
-                            empty mesh when none is active. Always has
-                            ``.empty`` / ``.axis_names`` / ``.shape``.
-  * :func:`shard_map`     — ``jax.shard_map`` or the experimental one, with
-                            the check_vma/check_rep kwarg rename papered over.
+  * :func:`make_mesh`     — Auto axis types on every axis.
+  * :func:`use_mesh`      — context manager activating a mesh.
+  * :func:`current_mesh`  — the ambient abstract mesh (``.empty`` when none).
+  * :func:`shard_map`     — ``jax.shard_map``.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 from jax.sharding import Mesh
 
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-_HAS_SET_MESH = hasattr(jax, "set_mesh")
-_HAS_GET_ABSTRACT = hasattr(jax.sharding, "get_abstract_mesh")
-_HAS_JAX_SHARD_MAP = hasattr(jax, "shard_map")
-
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    axis_shapes = tuple(axis_shapes)
+    """``jax.make_mesh`` with Auto axis types."""
     axis_names = tuple(axis_names)
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def use_mesh(mesh: Mesh):
-    """Context manager activating ``mesh`` for sharding constraints.
-
-    Modern JAX: ``jax.set_mesh(mesh)``. Legacy: ``Mesh`` is itself a context
-    manager that installs the physical mesh in thread resources — which is
-    exactly where :func:`current_mesh` looks on those versions.
-    """
-    if _HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager activating ``mesh`` for sharding constraints."""
+    return jax.set_mesh(mesh)
 
 
 def current_mesh():
-    """The ambient mesh, or an empty mesh object when none is active.
-
-    The return value is only inspected (``.empty``, ``.axis_names``,
-    ``.shape``) or handed to :func:`shard_map`; both the AbstractMesh of
-    modern JAX and the legacy physical Mesh satisfy that contract.
-    """
-    if _HAS_GET_ABSTRACT:
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as _src_mesh  # legacy thread-resources env
-    return _src_mesh.thread_resources.env.physical_mesh
+    """The ambient abstract mesh; ``.empty`` when none is active."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def axis_size(axis) -> int:
-    """``jax.lax.axis_size`` inside shard_map bodies, on any JAX version.
-
-    Legacy fallback: ``psum(1, axis)`` — on a Python-scalar constant this
-    hits the no-communication fast path and returns the static axis size.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+    """Size of a mesh axis inside a shard_map body."""
+    return jax.lax.axis_size(axis)
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every JAX version.
-
-    Modern JAX returns a dict; 0.4.x returned a one-element list of
-    per-program dicts (empty when analysis is unavailable).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()`` — a flat dict of the program's costs."""
+    return compiled.cost_analysis()
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across the check_vma (new) / check_rep (old) rename."""
-    if _HAS_JAX_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -92,10 +92,18 @@ def test_iterate_bf16_residency_parity(backend):
 
 
 def test_backend_capability_fallbacks():
-    # huber has no Pallas prox kind -> chunked; StackedProx is not
-    # coordinatewise -> reference (DESIGN.md §8 selection rules).
+    # only "auto" chooses: huber has no Pallas prox kind, so an explicit
+    # pallas request raises while auto lands on chunked; StackedProx is
+    # not coordinatewise -> reference (DESIGN.md §8 selection rules).
+    for be in ("pallas", "pallas_interpret"):
+        with pytest.raises(ValueError, match="cannot run loss"):
+            IterationEngine(loss=make_huber(1.0), tau=1.0,
+                            backend=be).resolve()
+        with pytest.raises(ValueError, match="cannot run loss"):
+            IterationEngine(loss=make_logistic(), tau=1.0,
+                            backend=be).resolve(jnp.float64)
     assert IterationEngine(loss=make_huber(1.0), tau=1.0,
-                           backend="pallas").resolve() == "chunked"
+                           backend="auto").resolve() == "chunked"
     sp = StackedProx(blocks=(make_l1(0.1), make_logistic()), sizes=(4, 8))
     assert IterationEngine(loss=sp.as_loss(), tau=1.0,
                            backend="chunked").resolve() == "reference"

@@ -6,7 +6,7 @@ import pytest
 
 from repro.kernels.flash_attn.ops import chunked_attention_xla, flash_attention
 from repro.kernels.flash_attn.ref import mha_ref
-from repro.kernels.gram.ops import gram, gram_with_rhs
+from repro.kernels.gram.ops import gram, gram_and_rhs
 from repro.kernels.gram.ref import gram_ref, gram_with_rhs_ref
 from repro.kernels.prox.ops import prox_update
 from repro.kernels.prox.ref import prox_update_ref
@@ -21,9 +21,12 @@ jax.config.update("jax_platform_name", "cpu")
 @pytest.mark.parametrize("m,n", [(256, 128), (1000, 130), (512, 64),
                                  (2048, 512), (77, 33)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_gram_matches_ref(m, n, dtype):
+@pytest.mark.parametrize("feature_major", [False, True])
+def test_gram_matches_ref(m, n, dtype, feature_major):
+    """Both panel orientations; ragged blocks along m (masked) and n."""
     D = jax.random.normal(jax.random.PRNGKey(0), (m, n), dtype)
-    G1 = gram(D, block_m=256, block_n=128, interpret=True)
+    G1 = gram(D, block_m=256, block_n=128, interpret=True,
+              feature_major=feature_major)
     G2 = gram_ref(D)
     tol = 5e-6 * m if dtype == jnp.bfloat16 else 2e-6 * m
     np.testing.assert_allclose(np.asarray(G1), np.asarray(G2),
@@ -47,11 +50,13 @@ def test_gram_output_is_psd_and_symmetric():
 
 
 @pytest.mark.parametrize("m,n", [(512, 100), (999, 65)])
-def test_gram_with_rhs(m, n):
+@pytest.mark.parametrize("feature_major", [False, True])
+def test_gram_with_rhs(m, n, feature_major):
     key = jax.random.PRNGKey(3)
     D = jax.random.normal(key, (m, n))
     b = jax.random.normal(jax.random.PRNGKey(4), (m,))
-    G1, c1 = gram_with_rhs(D, b, interpret=True)
+    G1, c1 = gram_and_rhs(D, b, block_m=256, interpret=True,
+                          feature_major=feature_major)
     G2, c2 = gram_with_rhs_ref(D, b)
     np.testing.assert_allclose(np.asarray(c1), np.asarray(c2), rtol=3e-5,
                                atol=1e-3)

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.admm_iter.ops import admm_iter
+from repro.kernels.admm_iter.ops import admm_iter_full
 from repro.kernels.admm_iter.ref import admm_iter_ref
 
 jax.config.update("jax_platform_name", "cpu")
@@ -18,21 +18,31 @@ CASES = [
 ]
 
 
+@pytest.mark.parametrize("feature_major", [False, True])
 @pytest.mark.parametrize("m,n,dt,kind", CASES)
-def test_fused_iter_matches_ref(m, n, dt, kind):
+def test_fused_iter_matches_ref(m, n, dt, kind, feature_major):
+    """Both panel orientations (row panels of D, feature panels of D^T),
+    with a ragged last block where 512 does not divide m: pad rows add
+    nothing to d, w or v."""
     ks = jax.random.split(jax.random.PRNGKey(0), 5)
     D = jax.random.normal(ks[0], (m, n), dt)
     aux = jnp.sign(jax.random.normal(ks[1], (m,)))
     y = jax.random.normal(ks[2], (m,))
     lam = jax.random.normal(ks[3], (m,))
     x = jax.random.normal(ks[4], (n,)) * 0.1
-    y1, l1, d1 = admm_iter(D, aux, y, lam, x, kind=kind, delta=2.0,
-                           block_m=512, interpret=True)
+    y1, l1, d1, w1, v1 = admm_iter_full(
+        D, aux, y, lam, x, kind=kind, delta=2.0, block_m=512,
+        interpret=True, feature_major=feature_major)
     y2, l2, d2 = admm_iter_ref(D, aux, y, lam, x, kind=kind, delta=2.0)
+    Df = D.astype(jnp.float32)
+    w2 = jnp.dot(y2 - y, Df, precision="highest")
+    v2 = jnp.dot(l2, Df, precision="highest")
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), atol=2e-5)
     np.testing.assert_allclose(np.asarray(l1), np.asarray(l2), atol=2e-5)
-    np.testing.assert_allclose(np.asarray(d1), np.asarray(d2),
-                               rtol=2e-5, atol=2e-3 * float(jnp.max(jnp.abs(d2))))
+    for got, want in ((d1, d2), (w1, w2), (v1, v2)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=2e-5,
+            atol=2e-3 * float(jnp.max(jnp.abs(want))))
 
 
 def test_fused_iter_advances_admm_exactly():
@@ -54,8 +64,9 @@ def test_fused_iter_advances_admm_exactly():
     # kernel path: x from the same solve, then the fused body
     d0 = jnp.einsum("mn,m->n", D, (y - lam)[0])
     x_k = gram_lib.gram_solve(L, d0)
-    yk, lk, dk = admm_iter(D, labels, y[0], lam[0], x_k,
-                           kind="logistic", delta=1.0 / tau, interpret=True)
+    yk, lk, dk, _, _ = admm_iter_full(D, labels, y[0], lam[0], x_k,
+                                      kind="logistic", delta=1.0 / tau,
+                                      interpret=True)
     np.testing.assert_allclose(np.asarray(yk), np.asarray(y_ref[0]),
                                atol=3e-5)
     np.testing.assert_allclose(np.asarray(lk), np.asarray(lam_ref[0]),

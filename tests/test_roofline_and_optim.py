@@ -48,14 +48,20 @@ def test_parse_collectives_byte_accounting():
 
 
 def test_roofline_terms_bottleneck():
-    t = roofline_terms(197e12, 100e9, 1e9)     # 1s compute, tiny others
+    v5e = "TPU v5 lite"
+    t = roofline_terms(197e12, 100e9, 1e9, v5e)   # 1s compute, tiny others
     assert t["bottleneck"] == "compute"
     assert abs(t["compute_s"] - 1.0) < 1e-9
-    t = roofline_terms(1e9, 819e9, 1e9)        # 1s memory
+    t = roofline_terms(1e9, 819e9, 1e9, v5e)      # 1s memory
     assert t["bottleneck"] == "memory"
-    t = roofline_terms(1e9, 1e9, 50e9)         # 1s collective
+    t = roofline_terms(1e9, 1e9, 50e9, v5e)       # 1s collective
     assert t["bottleneck"] == "collective"
     assert t["compute_fraction_of_bound"] < 0.01
+
+
+def test_roofline_peaks_refuse_unknown_device_kind():
+    with pytest.raises(ValueError, match="no peaks for device kind"):
+        roofline_terms(1e9, 1e9, 1e9, "cpu")
 
 
 def _quadratic_problem():

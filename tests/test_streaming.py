@@ -241,7 +241,7 @@ def test_staged_tuple_payloads_and_abandonment(classif):
 
 def test_sweep_padded_rows_do_not_leak(classif):
     """Zero pad rows of the tail block contribute nothing to d and the
-    stopping-rule scalars (the gram.blocked_rows zero-row argument,
+    stopping-rule scalars (the zero-row argument,
     streaming edition)."""
     D, a = _flat(classif)
     eng = IterationEngine(loss=make_logistic(), tau=0.1,
