@@ -263,24 +263,17 @@ class UnwrappedADMM:
         ex = LocalExecutor(self.engine, D, aux=aux,
                            gram_block_rows=self.gram_block_rows)
 
-        def _drive(obs_arg):
-            return solve_with_executor(
-                ex, loss=self.loss, tau=self.tau, rho=self.rho,
-                eps_rel=self.eps_rel, eps_abs=self.eps_abs,
-                max_iters=max_iters, x0=x0, record=record, reg=reg,
-                checkpoint_dir=checkpoint_dir,
-                checkpoint_every=checkpoint_every, resume=resume,
-                obs=obs_arg)
-
-        if obs is None or not obs.enabled:
-            return _drive(None)
-        with obs.span("admm_solve", max_iters=max_iters,
-                      sparse=isinstance(D, BlockCSR)):
-            res = _drive(obs)
+        res = solve_with_executor(
+            ex, loss=self.loss, tau=self.tau, rho=self.rho,
+            eps_rel=self.eps_rel, eps_abs=self.eps_abs,
+            max_iters=max_iters, x0=x0, record=record, reg=reg,
+            checkpoint_dir=checkpoint_dir,
+            checkpoint_every=checkpoint_every, resume=resume, obs=obs)
+        if obs is not None and obs.enabled:
             jax.block_until_ready(res.x)
-        obs.inc("admm.solves")
-        obs.record(event="solve_done", iters=int(res.iters),
-                   tau=self.tau, rho=self.rho)
+            obs.inc("admm.solves")
+            obs.record(event="solve_done", iters=int(res.iters),
+                       tau=self.tau, rho=self.rho)
         return res
 
     # -- sparse drivers: same semantics over a BlockCSR ---------------------

@@ -228,113 +228,113 @@ def solve_with_executor(ex: SolveExecutor, *, loss, tau: float,
     from repro.obs import NOOP
 
     obs = obs if obs is not None else NOOP
-    m, n, K = ex.m, ex.n, ex.ycols
-    m_eff, n_eff = m * K, n * K
+    with obs.span("admm_solve", executor=ex.name, max_iters=max_iters):
+        m, n, K = ex.m, ex.n, ex.ycols
+        m_eff, n_eff = m * K, n * K
 
-    with obs.span("gram_setup", executor=ex.name):
-        G = ex.setup(obs)
-        if reg is None:
-            L = gram_lib.gram_factor(G, ridge=rho / tau)
-            lmax = None
-        else:
-            L = None
-            lmax = power_lmax(G)
-
-    manager = None
-    if checkpoint_dir is not None:
-        from repro.checkpoint.manager import CheckpointManager
-        manager = CheckpointManager(checkpoint_dir)
-
-    k = 0
-    ex.resume_iter = 0
-    if manager is not None and resume and manager.latest_step() is not None:
-        tree, extra = manager.restore(ex.state_like(),
-                                      fallback=ex.restore_fallback)
-        if extra.get("kind") != ex.checkpoint_kind:
-            raise ex.error_cls(
-                f"not a {ex.kind_label} checkpoint: {extra}")
-        ex.verify_checkpoint(extra)
-        k = int(extra["iter"])
-        ex.resume_iter = k
-        d = ex.restore_state(k, tree)
-        x = tree["x"]            # returned as-is if no iterations remain
-    elif x0 is not None:
-        with obs.span("init_from_x0", executor=ex.name):
-            d = ex.init(x0)
-        x = ex.zero_x()
-    else:
-        d = ex.init(None)
-        x = ex.zero_x()
-
-    pad_obj = ex.pad_objective()
-    objs, rs, ss = [], [], []
-    k_conv = -1
-    while k < max_iters:
-        t_it = time.perf_counter()
-        with obs.span("x_solve", k=k + 1):
+        with obs.span("gram_setup", executor=ex.name):
+            G = ex.setup(obs)
             if reg is None:
-                x = gram_lib.gram_solve(L, jnp.asarray(d))
+                L = gram_lib.gram_factor(G, ridge=rho / tau)
+                lmax = None
             else:
-                x = composite_x_update(G, lmax, jnp.asarray(d),
-                                       jnp.asarray(x), tau, reg.prox,
-                                       reg.inner_iters)
-        t_sw = time.perf_counter()
-        with obs.span("sweep", k=k + 1):
-            sw = ex.sweep(x, k + 1)
-        sweep_s = time.perf_counter() - t_sw
-        if sw is None:           # degraded stop: best-so-far x
-            break
-        d = sw.d
-        r = float(jnp.sqrt(sw.r_sq))
-        s = tau * float(jnp.linalg.norm(sw.w))
-        eps_pri = np.sqrt(m_eff) * eps_abs + eps_rel * max(
-            float(jnp.sqrt(sw.dx_sq)), float(jnp.sqrt(sw.y_sq)))
-        eps_dual = np.sqrt(n_eff) * eps_abs + (
-            eps_rel * tau * float(jnp.linalg.norm(sw.v)))
-        k += 1
-        if record or obs.enabled:
-            obj = float(sw.obj) - pad_obj
-            if rho:
-                obj += 0.5 * rho * float(jnp.sum(jnp.asarray(x) ** 2))
-            if reg is not None:
-                obj += float(reg.value(jnp.asarray(x)))
-            if record:
-                objs.append(obj)
-                rs.append(r)
-                ss.append(s)
-            if obs.enabled:
-                dt = time.perf_counter() - t_it
-                obs.observe(f"{ex.name}.sweep_s", sweep_s)
-                obs.observe(f"{ex.name}.iter_s", dt)
-                obs.record(iter=k, objective=obj, primal_res=r,
-                           dual_res=s, eps_pri=float(eps_pri),
-                           eps_dual=float(eps_dual), tau=tau, rho=rho,
-                           iter_s=round(dt, 6),
-                           sweep_s=round(sweep_s, 6),
-                           executor=ex.name, backend=ex.backend,
-                           **ex.extra_record())
-        if manager is not None and checkpoint_every \
-                and k % checkpoint_every == 0:
-            state = ex.state_arrays(k)
-            if state is not None:
-                manager.save(k, {"x": x, "y": state["y"],
-                                 "lam": state["lam"], "d": d},
-                             extra={"kind": ex.checkpoint_kind, "iter": k,
-                                    **ex.checkpoint_extra()})
-                ex.on_checkpointed(k, state)
-        if r <= eps_pri and s <= eps_dual:
-            k_conv = k - 1
-            break
+                L = None
+                lmax = power_lmax(G)
 
-    converged = k_conv >= 0
-    ex.finish(k, converged)
-    history = None
-    if record:
-        acc = ex.acc
-        nan = jnp.full((len(objs),), jnp.nan, acc)
-        history = ADMMHistory(jnp.asarray(objs, acc), jnp.asarray(rs, acc),
-                              jnp.asarray(ss, acc), nan,
-                              jnp.asarray(k_conv, jnp.int32))
-    y, lam = ex.final_iterates()
+        manager = None
+        if checkpoint_dir is not None:
+            from repro.checkpoint.manager import CheckpointManager
+            manager = CheckpointManager(checkpoint_dir)
+
+        k = 0
+        ex.resume_iter = 0
+        if (manager is not None and resume
+                and manager.latest_step() is not None):
+            tree, extra = manager.restore(ex.state_like(),
+                                          fallback=ex.restore_fallback)
+            if extra.get("kind") != ex.checkpoint_kind:
+                raise ex.error_cls(
+                    f"not a {ex.kind_label} checkpoint: {extra}")
+            ex.verify_checkpoint(extra)
+            k = int(extra["iter"])
+            ex.resume_iter = k
+            d = ex.restore_state(k, tree)
+            x = tree["x"]            # returned as-is if no iterations remain
+        elif x0 is not None:
+            with obs.span("init_from_x0", executor=ex.name):
+                d = ex.init(x0)
+            x = ex.zero_x()
+        else:
+            d = ex.init(None)
+            x = ex.zero_x()
+
+        pad_obj = ex.pad_objective()
+        objs, rs, ss = [], [], []
+        k_conv = -1
+        while k < max_iters:
+            t_it = time.perf_counter()
+            with obs.span("x_solve", k=k + 1):
+                if reg is None:
+                    x = gram_lib.gram_solve(L, jnp.asarray(d))
+                else:
+                    x = composite_x_update(G, lmax, jnp.asarray(d),
+                                           jnp.asarray(x), tau, reg.prox,
+                                           reg.inner_iters)
+            with obs.span("sweep", k=k + 1):
+                sw = ex.sweep(x, k + 1)
+            if sw is None:           # degraded stop: best-so-far x
+                break
+            # the sweep was only enqueued: the host waits for it here
+            with obs.span("stop_rule", k=k + 1):
+                d = sw.d
+                r = float(jnp.sqrt(sw.r_sq))
+                s = tau * float(jnp.linalg.norm(sw.w))
+                eps_pri = np.sqrt(m_eff) * eps_abs + eps_rel * max(
+                    float(jnp.sqrt(sw.dx_sq)), float(jnp.sqrt(sw.y_sq)))
+                eps_dual = np.sqrt(n_eff) * eps_abs + (
+                    eps_rel * tau * float(jnp.linalg.norm(sw.v)))
+            k += 1
+            if record or obs.enabled:
+                obj = float(sw.obj) - pad_obj
+                if rho:
+                    obj += 0.5 * rho * float(jnp.sum(jnp.asarray(x) ** 2))
+                if reg is not None:
+                    obj += float(reg.value(jnp.asarray(x)))
+                if record:
+                    objs.append(obj)
+                    rs.append(r)
+                    ss.append(s)
+                if obs.enabled:
+                    dt = time.perf_counter() - t_it
+                    obs.observe(f"{ex.name}.iter_s", dt)
+                    obs.record(iter=k, objective=obj, primal_res=r,
+                               dual_res=s, eps_pri=float(eps_pri),
+                               eps_dual=float(eps_dual), tau=tau, rho=rho,
+                               iter_s=round(dt, 6),
+                               executor=ex.name, backend=ex.backend,
+                               **ex.extra_record())
+            if manager is not None and checkpoint_every \
+                    and k % checkpoint_every == 0:
+                state = ex.state_arrays(k)
+                if state is not None:
+                    manager.save(k, {"x": x, "y": state["y"],
+                                     "lam": state["lam"], "d": d},
+                                 extra={"kind": ex.checkpoint_kind, "iter": k,
+                                        **ex.checkpoint_extra()})
+                    ex.on_checkpointed(k, state)
+            if r <= eps_pri and s <= eps_dual:
+                k_conv = k - 1
+                break
+
+        converged = k_conv >= 0
+        ex.finish(k, converged)
+        history = None
+        if record:
+            acc = ex.acc
+            nan = jnp.full((len(objs),), jnp.nan, acc)
+            history = ADMMHistory(jnp.asarray(objs, acc), jnp.asarray(rs, acc),
+                                  jnp.asarray(ss, acc), nan,
+                                  jnp.asarray(k_conv, jnp.int32))
+        y, lam = ex.final_iterates()
     return ADMMResult(jnp.asarray(x), y, lam, jnp.asarray(k, jnp.int32),
                       history)

@@ -18,6 +18,7 @@ from repro.core.unwrapped import node_shape
 from repro.data.sparse import BlockCSR
 from repro.engine.streaming import SweepResult
 from repro.exec.base import SolveExecutor
+from repro.obs import annotate
 
 Array = jax.Array
 
@@ -106,12 +107,13 @@ def _fused_step(engine):
 
     @jax.jit
     def step(D, aux, y, lam, x):
-        st = engine.iterate(D, aux, y, lam, x, want_dual=True)
-        Dx = st.lam - lam + st.y
-        sw = SweepResult(
-            st.d, st.w, st.v,
-            jnp.sum((st.lam - lam) ** 2), jnp.sum(Dx * Dx),
-            jnp.sum(st.y * st.y), loss.value(Dx, aux))
-        return st.y, st.lam, sw
+        with annotate("step_trace"):     # runs only while jit traces
+            st = engine.iterate(D, aux, y, lam, x, want_dual=True)
+            Dx = st.lam - lam + st.y
+            sw = SweepResult(
+                st.d, st.w, st.v,
+                jnp.sum((st.lam - lam) ** 2), jnp.sum(Dx * Dx),
+                jnp.sum(st.y * st.y), loss.value(Dx, aux))
+            return st.y, st.lam, sw
 
     return step

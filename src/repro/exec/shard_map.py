@@ -24,6 +24,7 @@ from repro.core import gram as gram_lib
 from repro.core.distributed import compressed_psum, shard_rows
 from repro.engine.streaming import SweepResult
 from repro.exec.base import SolveExecutor
+from repro.obs import annotate
 from repro.sharding.compat import shard_map
 
 Array = jax.Array
@@ -172,21 +173,22 @@ def _shard_fns(engine, axes, mesh, has_aux: bool, compress: bool):
         return y, d
 
     def step_body(D, aux, y, lam, x, err):
-        Dres = engine.prepare(D)
-        st = engine.iterate(Dres, aux, y, lam, x, want_dual=True)
-        Dx = st.lam - lam + st.y
-        if compress:
-            d, e = compressed_psum(st.d, axes, err[0])
-            err = e[None]
-        else:
-            d = jax.lax.psum(st.d, axes)
-        sw = SweepResult(
-            d, jax.lax.psum(st.w, axes), jax.lax.psum(st.v, axes),
-            jax.lax.psum(jnp.sum((st.lam - lam) ** 2), axes),
-            jax.lax.psum(jnp.sum(Dx * Dx), axes),
-            jax.lax.psum(jnp.sum(st.y * st.y), axes),
-            jax.lax.psum(loss.value(Dx, aux), axes))
-        return st.y, st.lam, err, sw
+        with annotate("step_trace"):     # runs only while jit traces
+            Dres = engine.prepare(D)
+            st = engine.iterate(Dres, aux, y, lam, x, want_dual=True)
+            Dx = st.lam - lam + st.y
+            if compress:
+                d, e = compressed_psum(st.d, axes, err[0])
+                err = e[None]
+            else:
+                d = jax.lax.psum(st.d, axes)
+            sw = SweepResult(
+                d, jax.lax.psum(st.w, axes), jax.lax.psum(st.v, axes),
+                jax.lax.psum(jnp.sum((st.lam - lam) ** 2), axes),
+                jax.lax.psum(jnp.sum(Dx * Dx), axes),
+                jax.lax.psum(jnp.sum(st.y * st.y), axes),
+                jax.lax.psum(loss.value(Dx, aux), axes))
+            return st.y, st.lam, err, sw
 
     dspec = P(axes, None)
     espec = P(axes, None)

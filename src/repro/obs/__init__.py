@@ -2,11 +2,15 @@
 tracing spans + per-iteration telemetry, bundled per run directory.
 
 ``Observability`` is the object the solvers take: ``obs=None`` (or the
-module-level ``NOOP``) is the disabled fast path — ``span`` returns a
-reused null context manager, ``record``/``inc``/``observe`` return
-immediately — so instrumented code costs one attribute check when
-observability is off, and nothing is ever called from inside jitted
-code (host-side boundaries only).
+module-level ``NOOP``) is the disabled fast path — ``span`` returns the
+bare ``jax.profiler.TraceAnnotation`` and records nothing,
+``record``/``inc``/``observe`` return immediately — so instrumented code
+costs one attribute check and an annotation when observability is off.
+Spans are host-side boundaries, and land on the JAX profiler's timeline
+under their own names whether observability is on or not. One runs
+inside jitted code: each executor's step body enters ``annotate
+("step_trace")``, which runs only while JAX traces the step, so a
+profile counts the step's re-traces at no run-time cost.
 
 An enabled instance owns a run directory and writes three artifacts:
 
@@ -50,6 +54,7 @@ from repro.obs.slo import DEFAULT_OBJECTIVES, Objective, SLOTracker  # noqa: F40
 from repro.obs.telemetry import TelemetryWriter, jsonable, read_jsonl  # noqa: F401
 from repro.obs.trace import (  # noqa: F401
     Tracer,
+    annotate,
     is_ancestor,
     load_trace,
     span_hotspots,
@@ -73,6 +78,9 @@ class Observability:
         self.registry = MetricsRegistry()
         self.tracer = Tracer(enabled=self.enabled,
                              process_name=process_name)
+        # the tracer's own method, not a wrapper: a span is entered a few
+        # times an iteration, and a second call layer doubles its cost
+        self.span = self.tracer.span
         self.telemetry: Optional[TelemetryWriter] = None
         if self.enabled and dir is not None:
             os.makedirs(dir, exist_ok=True)
@@ -85,10 +93,7 @@ class Observability:
     def create(cls, dir: str, process_name: str = "main") -> "Observability":
         return cls(dir=dir, process_name=process_name)
 
-    # -- span / metric front doors (no-ops when disabled) -------------------
-    def span(self, name: str, **attrs):
-        return self.tracer.span(name, **attrs)
-
+    # -- metric front doors (no-ops when disabled) ---------------------------
     def inc(self, name: str, value: float = 1, **labels):
         if self.enabled:
             self.registry.inc(name, value, **labels)

@@ -1,21 +1,32 @@
-"""Low-overhead tracing spans -> Chrome-trace/Perfetto JSON (DESIGN.md §12).
+"""Low-overhead tracing spans -> Chrome-trace/Perfetto JSON (DESIGN.md §12)
+and the JAX profiler's timeline.
 
 ``Tracer.span("gram_pass", attrs=...)`` is a context manager that records
-one complete ("ph": "X") event; ``@tracer.traced()`` wraps a function.
-Events carry real OS pid/tid plus ``process_name`` / ``thread_name``
-metadata, so a multi-process cluster solve — coordinator + N workers,
-each exporting its own event list — merges into ONE timeline: load the
-exported JSON in ``chrome://tracing`` or https://ui.perfetto.dev and
-every process renders as its own track.
+one complete ("ph": "X") event. Events carry real OS pid/tid plus
+``process_name`` / ``thread_name`` metadata, so a multi-process cluster
+solve — coordinator + N workers, each exporting its own event list —
+merges into ONE timeline: load the exported JSON in ``chrome://tracing``
+or https://ui.perfetto.dev and every process renders as its own track.
 
-Clock contract: event timestamps (``ts``) are wall-clock microseconds
-(``time.time_ns``), the one clock processes on a host share, so merged
-cross-process events align; durations (``dur``) come from
-``time.perf_counter`` for sub-microsecond resolution within a span.
+Every span also enters ``jax.profiler.TraceAnnotation(name)`` (no
+attributes: they would be encoded on every call), enabled or not. While a
+profiler trace is running, the span is an event of the profiler's host
+plane under the same name, on the clock of the device's ops, so device
+idle time can be split by what the host was doing; with no trace running
+the annotation costs well under a microsecond.
 
-Disabled fast path: ``span`` on a disabled tracer returns a reused no-op
-context manager — no event dict, no timestamp read, no allocation — so
-instrumented code costs one attribute check when observability is off.
+Clock contract: the JSON export's timestamps (``ts``) are wall-clock
+microseconds (``time.time_ns``), the one clock processes on a host
+share, so merged cross-process events align; durations (``dur``) come
+from ``time.perf_counter`` for sub-microsecond resolution within a span.
+The profiler stamps its copy of the span with its own clock, the one its
+device events use. A span around asynchronous dispatch times the
+enqueue; the host waits where it reads a device value back.
+
+Disabled fast path: ``span`` on a disabled tracer returns the bare
+profiler annotation — no event dict, no timestamp read — so the Tracer
+records nothing and instrumented code costs one attribute check plus the
+annotation.
 
 Trace-context integration (DESIGN.md §16): when a request-scoped
 :class:`~repro.obs.context.TraceContext` is active (contextvar), each
@@ -30,7 +41,6 @@ sits in the queue).
 """
 from __future__ import annotations
 
-import functools
 import json
 import os
 import threading
@@ -40,22 +50,24 @@ from typing import List, Optional
 from repro.obs.context import TraceContext, current_context, use_context
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+_annotation = None     # jax.profiler.TraceAnnotation, imported on first use
 
 
-_NULL_SPAN = _NullSpan()
+def annotate(name: str):
+    """A ``jax.profiler.TraceAnnotation`` called ``name``: an event on the
+    profiler's host plane while a trace runs, next to nothing otherwise.
+    jax is imported on the first call, so importing ``repro.obs`` does
+    not import it."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name)
 
 
 class _Span:
     __slots__ = ("_tracer", "_name", "_attrs", "_t0_us", "_t0", "_ctx",
-                 "_cm")
+                 "_cm", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -63,6 +75,8 @@ class _Span:
         self._attrs = attrs
 
     def __enter__(self):
+        self._ann = annotate(self._name)
+        self._ann.__enter__()
         parent = current_context()
         if parent is not None:
             # This span is a new node in the request's trace: push a child
@@ -90,6 +104,7 @@ class _Span:
             if self._ctx.parent_id is not None:
                 attrs["parent_id"] = self._ctx.parent_id
         self._tracer._emit(self._name, self._t0_us, dur_us, attrs)
+        self._ann.__exit__(None, None, None)
         return False
 
     @property
@@ -118,23 +133,8 @@ class Tracer:
 
     def span(self, name: str, **attrs):
         if not self.enabled:
-            return _NULL_SPAN
+            return annotate(name)
         return _Span(self, name, attrs)
-
-    def traced(self, name: Optional[str] = None):
-        def deco(fn):
-            label = name or fn.__qualname__
-
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                if not self.enabled:
-                    return fn(*args, **kwargs)
-                with self.span(label):
-                    return fn(*args, **kwargs)
-
-            return wrapper
-
-        return deco
 
     def complete_at(self, name: str, t0_us: int, dur_s: float,
                     ctx: Optional[TraceContext] = None, **attrs):

@@ -1,19 +1,25 @@
 """Observability layer (DESIGN.md §12): registry thread-safety,
 histogram percentile accuracy, span nesting/attrs in the exported
-Chrome trace, telemetry-JSONL parity with ADMMHistory on a lasso
-solve, and cluster snapshot merging (registry + legacy ByteCounter)."""
+Chrome trace, the solve loop's spans on the JAX profiler's timeline,
+telemetry-JSONL parity with ADMMHistory on a lasso solve, and cluster
+snapshot merging (registry + legacy ByteCounter)."""
+import importlib.util
 import json
 import math
 import threading
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax.sharding import Mesh
 
 from repro.cluster.transport import ByteCounter
 from repro.core.prox import StackedProx, make_l1, make_least_squares
 from repro.core.unwrapped import UnwrappedADMM
 from repro.data.synthetic import lasso_problem
+from repro.exec import fit_on_executor, make_problem, synth_data
 from repro.obs import (
     METRICS_FILE,
     TELEMETRY_FILE,
@@ -184,6 +190,82 @@ def test_disabled_tracer_is_noop():
         pass
     assert obs.registry.snapshot()["counters"] == []
     obs.finish()   # no dir: must not write anything / raise
+
+
+# -- spans on the profiler's timeline ---------------------------------------
+
+def _trace_reduce():
+    """The benchmark's trace reader (bench/trace_reduce.py)."""
+    path = Path(__file__).resolve().parents[1] / "bench" / "trace_reduce.py"
+    spec = importlib.util.spec_from_file_location("_trace_reduce", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _profiled(log_dir, fn):
+    """Run ``fn`` under the JAX profiler; return its result and the
+    trace's plain events."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0         # annotations only, as the bench
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    return out, _trace_reduce().extract(str(log_dir))
+
+
+def test_enabled_span_is_also_a_profiler_event(tmp_path):
+    """An enabled span records its JSON event and, under the same name,
+    an event on the profiler's host plane."""
+    tr = Tracer(enabled=True)
+
+    def spans():
+        with tr.span("outer", k=1):
+            with tr.span("inner"):
+                pass
+
+    _, events = _profiled(tmp_path, spans)
+    host = {e[0] for e in events["host"]}
+    assert {"outer", "inner"} <= host
+    assert {e["name"] for e in tr.events() if e.get("ph") == "X"} == {
+        "outer", "inner"}
+
+
+@pytest.mark.parametrize("executor", ["local", "shard_map"])
+def test_solve_spans_on_profiler_timeline(tmp_path, executor):
+    """A fresh logistic solve, with observability off, leaves the
+    solve loop's spans on the profiler's host plane: one solve and one setup,
+    an x-update, a sweep and a stopping test per iteration inside the
+    solve, and one trace of the executor's step, inside the first sweep.
+    The disabled Tracer records nothing."""
+    prob = make_problem("logistic")
+    D, aux = synth_data(prob, m=2000, n=16, seed=3)
+    opts = ({"mesh": Mesh(np.array(jax.devices()[:1]), ("data",))}
+            if executor == "shard_map" else {})
+    obs = Observability(dir=None, enabled=False)
+
+    def solve():
+        res = fit_on_executor(prob, executor, D, aux, obs=obs, **opts)
+        return jax.block_until_ready(res)
+
+    res, events = _profiled(tmp_path, solve)
+    assert obs.tracer.events() == []
+    host = lambda name: _trace_reduce().host_spans(events, name)
+    (solve_span,) = host("admm_solve")
+    assert len(host("gram_setup")) == 1
+    iters = int(res.iters)
+    assert 1 < iters < 300
+    inside = lambda s, e: solve_span[0] <= s and e <= solve_span[1]
+    for name in ("gram_setup", "x_solve", "sweep", "stop_rule"):
+        got = host(name)
+        assert len(got) == (1 if name == "gram_setup" else iters), name
+        assert all(inside(s, e) for s, e in got), name
+    (step_trace,) = host("step_trace")
+    first_sweep = host("sweep")[0]
+    assert first_sweep[0] <= step_trace[0]
+    assert step_trace[1] <= first_sweep[1]
 
 
 # -- telemetry parity with ADMMHistory --------------------------------------
