@@ -8,6 +8,7 @@ driver. Accepts node-stacked dense (N, m_i, n) arrays, a flat dense
 """
 from __future__ import annotations
 
+from functools import lru_cache
 from typing import Optional
 
 import jax
@@ -98,11 +99,19 @@ class LocalExecutor(SolveExecutor):
         return self._y.reshape(shape), self._lam.reshape(shape)
 
 
+@lru_cache(maxsize=64)
 def _fused_step(engine):
     """Jitted ``(D, aux, y, lam, x) -> (y', lam', SweepResult)``: the
     engine's fused body plus the stopping-rule scalars in one dispatch.
-    Shared across LocalExecutor instances of the same engine config via
-    jit's own cache (the engine is a frozen dataclass)."""
+
+    Memoized on the frozen ``IterationEngine`` (loss, tau, backend,
+    block_m, residency), as ``engine.streaming._block_fns`` is: equal
+    engines get the same jitted step, whose own cache then keys shapes,
+    dtypes and shardings, so a second solve on an equal engine neither
+    traces nor lowers it. A loss compares by the identity of its
+    ``value``/``prox`` callables, so two ``make_logistic()`` calls give
+    two keys. The bound caps the steps a long-running process keeps
+    alive when every request builds a fresh loss."""
     loss = engine.loss
 
     @jax.jit

@@ -13,6 +13,7 @@ as they are, never through the host.
 """
 from __future__ import annotations
 
+from functools import lru_cache
 from typing import Optional, Tuple
 
 import jax
@@ -157,8 +158,13 @@ class ShardMapExecutor(SolveExecutor):
         return y[None], lam[None]
 
 
+@lru_cache(maxsize=64)
 def _shard_fns(engine, axes, mesh, has_aux: bool, compress: bool):
-    """Jitted (gram, init, step) shard_map bodies for one engine config."""
+    """Jitted (gram, init, step) shard_map bodies for one engine config.
+
+    Memoized on every argument (the frozen engine, the axes tuple, the
+    ``Mesh`` and two flags), like ``exec.local._fused_step``: executors
+    with equal arguments share the jitted bodies and trace them once."""
     yspec = P(axes)                       # rows sharded, trailing dims full
     loss = engine.loss
 
