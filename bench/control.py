@@ -7,12 +7,15 @@ For each seed, one run of the cell as the benchmark makes it (a short
 window, then the check), and one run with the control in the program's
 place: the plain reference computed in the precision below the one the
 configuration states (``--precision``, default ``high``: three bf16
-products for f32 at HIGHEST). Prints each run's compared numbers, one
-JSON line per run. The benchmark's own runs never run this.
+products for f32 at HIGHEST). On a cell over several chips,
+``--fault-seeds`` adds runs of the program with the exchange between
+chips left out (:func:`exchange_dropped`). Prints each run's compared
+numbers, one JSON line per run. The benchmark's own runs never run this.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 from pathlib import Path
@@ -26,11 +29,12 @@ def control_fit(precision: str):
 
     reference = harness.bench_module("reference")
     admm = harness.bench_module("jobs.admm")
+    data = harness.bench_module("data")
 
     def fit(job):
         eps_rel, eps_abs = admm.tolerance(job.mix, job.cfg["rows"])
         out = reference.admm(
-            [(job.D, job.aux)],
+            data.blocks(job.D, job.aux),
             loss=job.mix["loss"], tau=job.mix["tau"], rho=job.mix["rho"],
             C=job.mix.get("C", 1.0), eps_rel=eps_rel, eps_abs=eps_abs,
             max_iters=job.mix["max_iters"], precision=precision)
@@ -40,6 +44,30 @@ def control_fit(precision: str):
     return fit
 
 
+@contextlib.contextmanager
+def exchange_dropped():
+    """A planted fault of the program: while on, the sharded step's
+    n-vector sums (d, w, v) stay each chip's own rows' sums and are not
+    added across chips; the Gram and the scalars are still all-reduced.
+    Steps built while it is on keep it, so the step cache is emptied on
+    the way in and out."""
+    import jax
+    from repro.exec import shard_map
+
+    psum = jax.lax.psum
+
+    def psum_but_vectors(x, axis_name, **kw):
+        return x if jax.numpy.ndim(x) == 1 else psum(x, axis_name, **kw)
+
+    jax.lax.psum = psum_but_vectors
+    shard_map._shard_fns.cache_clear()
+    try:
+        yield
+    finally:
+        jax.lax.psum = psum
+        shard_map._shard_fns.cache_clear()
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -47,6 +75,9 @@ def main(argv=None):
                     help="seeds of the program's runs")
     ap.add_argument("--control-seeds", type=int, nargs="*", default=(),
                     help="seeds of the control's runs")
+    ap.add_argument("--fault-seeds", type=int, nargs="*", default=(),
+                    help="seeds of the program's runs with the exchange "
+                         "between chips left out")
     ap.add_argument("--precision", default="high")
     args = ap.parse_args(argv)
     sys.path.insert(0, str(BENCH))
@@ -54,11 +85,15 @@ def main(argv=None):
 
     log = lambda m: print(m, file=sys.stderr, flush=True)
     runs = ([(s, "program") for s in args.seeds]
-            + [(s, "control") for s in args.control_seeds])
+            + [(s, "control") for s in args.control_seeds]
+            + [(s, "exchange_dropped") for s in args.fault_seeds])
     for seed, side in runs:
         hook = control_fit(args.precision) if side == "control" else None
-        res = harness.run_cell(args.workload, seed, 0.0, False, log=log,
-                               fit_hook=hook)
+        fault = (exchange_dropped() if side == "exchange_dropped"
+                 else contextlib.nullcontext())
+        with fault:
+            res = harness.run_cell(args.workload, seed, 0.0, False, log=log,
+                                   fit_hook=hook)
         row = {"cell": args.workload, "seed": seed, "side": side,
                "precision": args.precision if hook else "program",
                "checks": res["checks"], "attempted": res["attempted"],

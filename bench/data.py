@@ -21,6 +21,11 @@ per-node heterogeneity shift, and imports nothing from it, so that a
 change to the program cannot move the yardstick; nor can a change to
 how JAX splits its keys, since the counter hash is written out here. A
 config names its generator under ``"data"``.
+
+On several chips the rows are split over them in blocks, in order
+(:func:`row_shardings`): each chip generates only its own block, the
+bytes are those one chip would generate, and the check gets the blocks
+one by one (:func:`blocks`).
 """
 from __future__ import annotations
 
@@ -30,6 +35,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 BASE_KEY = (0x243F6A88, 0x85A308D3)     # the one dataset of every seed
 _ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
@@ -75,14 +81,55 @@ def classification(m: int, n: int, dtype, informative: int = 5,
 
 
 GENERATORS = {"classification": classification}
+ROWS = "data"        # the mesh axis the rows are split over
 
 
-def make(kind: str, m: int, n: int, dtype):
-    """The dataset, generated on the default device in one jitted program
-    that writes D's own buffer. It is the same for every seed: the seed
-    acts through :func:`signs`."""
+def row_mesh(devices) -> Mesh:
+    """A 1-D mesh over ``devices`` whose one axis splits the rows."""
+    return Mesh(np.array(devices), (ROWS,))
+
+
+def row_shardings(mesh: Mesh):
+    """(D's, the labels') shardings with the rows split over ``mesh``, as
+    ``core/distributed.shard_rows`` places them: chip i holds the i-th
+    block of rows, whole."""
+    return (NamedSharding(mesh, P((ROWS,), None)),
+            NamedSharding(mesh, P((ROWS,))))
+
+
+def generator(kind: str, m: int, n: int, dtype, shardings=None):
+    """The jitted program that generates the dataset. Without
+    ``shardings`` it writes D on the default device; with them (see
+    :func:`row_shardings`) each chip writes only its own rows."""
     gen = GENERATORS[kind]
-    return jax.jit(lambda: gen(m, n, dtype))()
+    fn = lambda: gen(m, n, dtype)
+    if shardings is None:
+        return jax.jit(fn)
+    return jax.jit(fn, out_shardings=shardings)
+
+
+def make(kind: str, m: int, n: int, dtype, shardings=None):
+    """The dataset, generated on the device in one jitted program that
+    writes D's own buffer (:func:`generator`). It is the same for every
+    seed, and for every placement: the seed acts through :func:`signs`."""
+    return generator(kind, m, n, dtype, shardings)()
+
+
+def blocks(D, aux):
+    """[(D_i, labels_i), ...]: the rows and labels each chip holds, in row
+    order, each block an array on its own chip (no copy); one block, the
+    arrays themselves, on one chip."""
+    if len(D.addressable_shards) == 1:
+        return [(D, aux)]
+    first_row = lambda s: s.index[0].start
+    out = []
+    for d, a in zip(sorted(D.addressable_shards, key=first_row),
+                    sorted(aux.addressable_shards, key=first_row)):
+        if d.device != a.device or d.index[0] != a.index[0]:
+            raise ValueError(f"rows {d.index[0]} of D on {d.device} but "
+                             f"labels {a.index[0]} on {a.device}")
+        out.append((d.data, a.data))
+    return out
 
 
 def signs(seed: int, fit: int, n: int) -> np.ndarray:

@@ -165,9 +165,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     bench_trace = bench_module("trace_reduce")
 
     m, n, dtype = cfg["rows"], cfg["features"], cfg["dtype"]
-    D, aux = bench_data.make(cfg["data"], m, n, dtype)
+    mesh = shardings = None
+    if cell.chips > 1:
+        mesh = bench_data.row_mesh(devs)
+        shardings = bench_data.row_shardings(mesh)
+    D, aux = bench_data.make(cfg["data"], m, n, dtype, shardings)
     job_kind = bench_module(f"jobs.{mix['job']}")
-    job = job_kind.Job(cfg, mix, D, aux)
+    job = job_kind.Job(cfg, mix, D, aux, mesh)
     del D
     if fit_hook is not None:
         job.fit = lambda: fit_hook(job)
@@ -267,11 +271,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
 
     # The program's state goes before the reference runs: the peak above
     # is the program's alone, and the reference needs room of its own.
-    D_last, aux_last = job.D, job.aux
+    blocks = bench_data.blocks(job.D, job.aux)
     del job
     gc.collect()
-    checks = job_kind.check(mix, cfg, [(D_last, aux_last)], fits, cell.limits, bench_module("reference"),
-                            log)
+    checks = job_kind.check(mix, cfg, blocks, fits, cell.limits,
+                            bench_module("reference"), log)
     result["correct"] = bool(checks) and all(
         v <= lim for v, lim in checks.values())
     result["metrics"] = metrics

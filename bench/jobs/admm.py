@@ -3,6 +3,11 @@
 and the check of its answers against the plain reference.
 
 The fit is ``UnwrappedADMM.solve`` on one chip (a ``LocalExecutor``).
+A config with ``"executor": "shard_map"`` runs each fit as
+``launch.fit --executor shard_map`` does: a ``ShardMapExecutor`` over the
+cell's chips, holding D's rows where the harness placed them, under the
+same driver (paper Alg. 2). Its engine is built once per job around the
+job's one loss, so the step is traced once per process, in the warm-up.
 Loss, tau and rho come from the program's own
 table (``launch.fit._admm_params``), and the job refuses to run when
 that table no longer says what the mix states, because the reference
@@ -22,8 +27,11 @@ def tolerance(mix: dict, m: int):
     return mix["eps_rel"], mix["eps_abs_per_sqrt_row"] * math.sqrt(m)
 
 
+EXECUTORS = ("local", "shard_map")
+
+
 class Job:
-    def __init__(self, cfg: dict, mix: dict, D, aux):
+    def __init__(self, cfg: dict, mix: dict, D, aux, mesh=None):
         from repro.launch.fit import _admm_params
 
         self.loss, rho, tau, _ = _admm_params(mix["problem"])
@@ -38,10 +46,22 @@ class Job:
         eps_rel, eps_abs = tolerance(mix, cfg["rows"])
         self.tol = dict(eps_rel=eps_rel, eps_abs=eps_abs,
                         max_iters=mix["max_iters"])
+        executor = cfg.get("executor", "local")
+        if executor not in EXECUTORS:
+            raise SystemExit(f"config {cfg['name']!r} names executor "
+                             f"{executor!r}; known: {EXECUTORS}")
+        self.mesh, self.engine = mesh, None
+        if executor == "shard_map":
+            from repro.core.unwrapped import UnwrappedADMM
+
+            self.engine = UnwrappedADMM(loss=self.loss, tau=tau,
+                                        rho=rho).engine
 
     def fit(self):
         """One cold-start fit to tolerance on the current ``self.D``.
         Returns (x on the host, iterations)."""
+        if self.engine is not None:
+            return self._fit_sharded()
         from repro.core.unwrapped import UnwrappedADMM
 
         tol = self.tol
@@ -51,13 +71,22 @@ class Job:
             self.D, self.aux, max_iters=tol["max_iters"])
         return np.asarray(res.x), int(res.iters)
 
+    def _fit_sharded(self):
+        from repro.exec import ShardMapExecutor, solve_with_executor
+
+        ex = ShardMapExecutor(self.engine, self.D, aux=self.aux,
+                              mesh=self.mesh)
+        res = solve_with_executor(ex, loss=self.loss, tau=self.tau,
+                                  rho=self.rho, **self.tol)
+        return np.asarray(res.x), int(res.iters)
+
 
 def check(mix, cfg, blocks, fits, limits, reference, log) -> dict:
     """{name: (number, limit)}: every fit of the window against the plain
     reference, run once on the last fit's data (``blocks``: its rows and
-    labels as [(D, labels)]). Fit k's data is the
-    last fit's with some columns negated, so its exact answer is the last
-    one's with the same entries negated.
+    labels as [(D_i, labels_i), ...], one block per chip). Fit k's data
+    is the last fit's with some columns negated, so its exact answer is
+    the last one's with the same entries negated.
 
     ``x_gap``: the widest relative gap between a fit's x and the
     reference's x after as many iterations. ``stop_residual``: the

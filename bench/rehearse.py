@@ -9,9 +9,9 @@ collectives the compiler put in (op, count, bytes). What the chip's
 compiler would refuse (more VMEM than a kernel may use, more HBM than the
 chip has) fails here, at no chip time. Programs: the data generation and
 sign flip of each configuration; for ``fig1_share`` the Gram, the
-per-iteration step and the x-update; for four chips' share of
-``star_share`` (14,848,000 x 307 f32, rows sharded over a 2x2 mesh) the
-``ShardMapExecutor``'s Gram and step on a 2x2 mesh.
+per-iteration step and the x-update; for ``star_share_x4`` (14,848,000 x
+307 f32 over the four chips of a 2x2 v5e) the harness's row-sharded
+generation and flip, and the ``ShardMapExecutor``'s Gram and step.
 """
 from __future__ import annotations
 
@@ -21,8 +21,6 @@ import re
 import sys
 from collections import Counter
 from pathlib import Path
-
-import numpy as np
 
 BENCH = Path(__file__).resolve().parent
 COLLECTIVE = re.compile(r"= (\(.*?\)|\S+) (all-reduce|all-gather|reduce-scatter|"
@@ -70,17 +68,15 @@ def main(groups=("star", "fig1", "x4")):
             continue
         c = cfgs[name]
         m, n, dt = c["rows"], c["features"], jnp.dtype(c["dtype"])
-        gen = jax.jit(lambda: bench_data.GENERATORS[c["data"]](m, n, dt),
-                      out_shardings=(one, one))
-        summary(f"{name} generate", gen.lower().compile())
+        summary(f"{name} generate", bench_data.generator(
+            c["data"], m, n, dt, (one, one)).lower().compile())
         summary(f"{name} flip", bench_data.flip.lower(
             arg((m, n), dt), arg((n,))).compile())
 
     if "fig1" in groups:
         fig1(cfgs["fig1_share"], arg)
     if "x4" in groups:
-        star = cfgs["star_share"]
-        x4({**star, "rows": 4 * star["rows"]}, topo, arg)
+        x4(cfgs["star_share_x4"], topo, arg)
 
 
 def fig1(c, arg):
@@ -110,33 +106,32 @@ def fig1(c, arg):
 
 
 def x4(c, topo, arg):
-    import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     import data as bench_data
     from repro.engine import IterationEngine
     from repro.exec.shard_map import _shard_fns
     from repro.launch.fit import _admm_params
 
-    m, n = c["rows"], c["features"]
+    m, n, dt, chips = c["rows"], c["features"], jnp.dtype(c["dtype"]), \
+        c["chips"]
     loss, rho, tau, _ = _admm_params("logistic")
-    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
-    rows = NamedSharding(mesh, P("data", None))
-    rvec = NamedSharding(mesh, P("data"))
-    rep = NamedSharding(mesh, P())
+    mesh = bench_data.row_mesh(topo.devices[:chips])
+    rows, rvec = bench_data.row_shardings(mesh)
+    summary("star_share_x4 generate", bench_data.generator(
+        c["data"], m, n, dt, (rows, rvec)).lower().compile())
+    D = arg((m, n), dt, rows)
+    summary("star_share_x4 flip", bench_data.flip.lower(
+        D, arg((n,), jnp.float32, NamedSharding(mesh, P()))).compile())
     gram_fn, _, step_fn = _shard_fns(
-        IterationEngine(loss=loss, tau=tau, backend="pallas"), ("data",),
-        mesh, True, False)
-    D = arg((m, n), jnp.float32, rows)
+        IterationEngine(loss=loss, tau=tau, backend="pallas"),
+        (bench_data.ROWS,), mesh, True, False)
     summary("star_share_x4 gram", gram_fn.lower(D).compile())
     v = arg((m,), jnp.float32, rvec)
     summary("star_share_x4 step", step_fn.lower(
-        D, v, v, v, arg((n,), jnp.float32, rep),
-        arg((4, n), jnp.float32, rows)).compile())
-    gen = jax.jit(lambda: bench_data.classification(m, n, jnp.float32),
-                  out_shardings=(rows, rvec))
-    summary("star_share_x4 generate", gen.lower().compile())
+        D, v, v, v, arg((n,), jnp.float32, NamedSharding(mesh, P())),
+        arg((chips, n), jnp.float32, rows)).compile())
 
 
 if __name__ == "__main__":
